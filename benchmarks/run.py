@@ -38,6 +38,7 @@ import time
 
 from benchmarks import bench_rknn
 from benchmarks.common import DEFAULT_SCALE
+from repro.compile_cache import enable_compile_cache
 
 BENCHES = [
     ("table2", bench_rknn.table2_indexing),
@@ -217,6 +218,7 @@ def print_trend() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--scale", type=float, default=DEFAULT_SCALE)
     ap.add_argument(

@@ -10,12 +10,14 @@ as shims; see docs/API.md for the migration table.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import RkNNEngine, available_backends
 from repro.core.brute import rknn_brute_np
 from repro.data.spatial import facility_user_split, road_network_points
 
 
 def main() -> None:
+    enable_compile_cache()
     # a road-network-like city: 100k points, 1000 facilities, rest users
     points = road_network_points(100_000, seed=7)
     facilities, users = facility_user_split(points, 1_000, seed=7)

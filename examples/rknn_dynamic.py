@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import RkNNConfig, RkNNEngine
 from repro.data.spatial import facility_user_split, road_network_points
 from repro.dynamic import DynamicEngine, UpdateBatch
@@ -29,6 +30,7 @@ from repro.workloads import drifting_users, facility_churn
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--users", type=int, default=20_000)
     ap.add_argument("--facilities", type=int, default=400)

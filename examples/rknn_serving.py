@@ -15,12 +15,14 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import RkNNConfig, RkNNEngine
 from repro.core.brute import rknn_brute_np
 from repro.data.spatial import facility_user_split, road_network_points
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--users", type=int, default=200_000)
     ap.add_argument("--facilities", type=int, default=1_000)

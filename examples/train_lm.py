@@ -10,10 +10,12 @@ import json
 import shutil
 import tempfile
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import train_main
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2_130m")
     ap.add_argument("--steps", type=int, default=200)

@@ -81,6 +81,25 @@ def serve_shardings(mesh):
     return user_sh, scene_sh, out_sh
 
 
+def _auto_axes(mesh):
+    """``mesh`` with every axis of type ``Auto``.
+
+    The sharded serving steps below are written for sharding propagation:
+    they pin only their inputs and outputs (``in_shardings`` /
+    ``out_shardings``).  On a mesh with ``Explicit`` axes — what
+    ``jax.make_mesh`` builds by default — every intermediate carries a
+    sharding type instead, and the steps' gathers, which declare none, are
+    refused.  The devices and axis names are unchanged.
+    """
+    from jax.sharding import AxisType, Mesh
+
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(
+        mesh.devices, mesh.axis_names, axis_types=(AxisType.Auto,) * mesh.devices.ndim
+    )
+
+
 @dataclasses.dataclass(frozen=True)
 class RkNNConfig:
     """Construction-time knobs of :class:`RkNNEngine` (see docs/API.md).
@@ -297,7 +316,7 @@ class RkNNEngine:
             config = dataclasses.replace(config, **overrides)
         get_backend(config.backend)  # validate eagerly
         self.config = config
-        self.mesh = mesh
+        self.mesh = None if mesh is None else _auto_axes(mesh)
         self.metrics = MetricsRegistry()
         self.stats = EngineStats(self.metrics)
         self._init_metrics()
@@ -330,8 +349,8 @@ class RkNNEngine:
             from repro.obs.flight import FlightRecorder
 
             self.flight = FlightRecorder(self, dir=config.flight_dir)
-        if mesh is not None:
-            self._init_mesh(self._snap, mesh)
+        if self.mesh is not None:
+            self._init_mesh(self._snap, self.mesh)
         if config.warm_store:
             from repro.persist import warm_start
 

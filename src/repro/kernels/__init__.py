@@ -4,15 +4,14 @@
                      single-query and batched (``[Q]`` grid axis) variants
 * ``rank_count``   — distance-rank counting (brute / "InfZone-GPU" baseline)
 * ``grid_raycast`` — grid-culled counting (the TPU BVH analogue):
-                     cell-bucketed scalar-prefetch kernels, single-query
-                     and batched ``(q, user-block)`` variants, plus the
-                     host-side bucketing / plane-packing helpers
+                     cell-bucketed scalar-prefetch ``(q, user-block)``
+                     kernel, plus the host-side bucketing / plane-packing
+                     helpers
 * ``ops``          — jit'd public wrappers (padding, backend selection,
                      batched multi-query dispatch)
 * ``ref``          — pure-jnp oracles used by the allclose sweeps
 """
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.ops import (
     grid_count_cells,
     grid_count_cells_batch,
@@ -27,5 +26,4 @@ __all__ = [
     "raycast_count_batch",
     "grid_count_cells",
     "grid_count_cells_batch",
-    "tpu_compiler_params",
 ]

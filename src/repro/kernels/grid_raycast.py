@@ -1,4 +1,4 @@
-"""Pallas TPU kernels: grid-culled hit counting (the BVH-analogue path).
+"""Pallas TPU kernel: grid-culled hit counting (the BVH-analogue path).
 
 For NON-pruned or conservatively-pruned scenes (paper §4.8, Table 3) the
 occluder count is large enough that the dense sweep wastes work; the
@@ -12,23 +12,31 @@ run to a multiple of the block size; the kernel's grid iterates user
 blocks with a **scalar-prefetch map** selecting, per step, which cell's
 (padded) triangle-coefficient planes to stage into VMEM — predictable
 block gathers instead of the BVH's pointer chasing.  Each program
-instance evaluates ``[BU x L]`` edge functions (and, on the single-query
-path, adds ``base[cell]``).
+instance evaluates ``[BU x L]`` edge functions.
 
-The batched form (:func:`grid_raycast_cells_batch`) extends the grid to
-``(Q, user-block)``: the user→cell sort is computed ONCE per batch (all
-stacked scenes share one domain rect) and each program stages one query's
-planes for one cell — this replaces the batched jnp path's gather-bound
-``[Q, N, L, 3, 3]`` temporary with ``[BU x L]`` edge evaluations plus a
-``base[q, cell]`` add.
+The kernel (:func:`grid_raycast_cells_batch`; a single query is the
+batch at ``Q = 1``) iterates ``(Q, user-block)``: the user→cell sort is
+computed ONCE per batch (all stacked scenes share one domain rect) and
+each program stages one query's planes for one cell — this replaces the
+batched jnp path's gather-bound ``[Q, N, L, 3, 3]`` temporary with
+``[BU x L]`` edge evaluations plus a ``base[q, cell]`` add.
+
+Tiling on the compiled (Mosaic) path: a block's last two dimensions
+must be multiples of ``(8, 128)`` or span the whole array, so the sorted
+users travel as a ``[1, n_blocks*block]`` row read in ``(1, block)``
+blocks and the counts as ``[Q, 1, n_blocks*block]`` written in
+``(1, 1, block)`` blocks — ``block`` a multiple of the 128-lane tile
+(:func:`min_cell_block`).  Interpret mode runs the same kernel code.
 
 Validated against the ``core.grid`` jnp oracle in ``tests/test_kernels.py``
-and ``tests/test_grid_pallas.py``.
+and ``tests/test_grid_pallas.py``; ``tests/test_tpu_compile.py`` compiles
+it for a TPU v5e at serving widths.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import TYPE_CHECKING
 
 import numpy as np
 
@@ -37,16 +45,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.grid import OccluderGrid
-from repro.kernels.compat import tpu_compiler_params
+if TYPE_CHECKING:  # repro.core imports this module
+    from repro.core.grid import OccluderGrid
 
 __all__ = [
     "auto_cell_block",
+    "min_cell_block",
     "measured_pad_waste",
     "prepare_cell_buckets",
     "pack_cell_coeff_planes",
     "repack_cell_coeff_planes",
-    "grid_raycast_cells",
     "grid_raycast_cells_batch",
     "unsort_cell_counts",
 ]
@@ -56,17 +64,30 @@ __all__ = [
 _PAD_COORD = np.float32(2e9)
 
 
+def min_cell_block() -> int:
+    """Smallest per-cell user block the bucketed kernel runs with.
+
+    The compiled kernel reads users and writes counts in lane-dimension
+    blocks, which must be multiples of the 128-lane tile; interpret mode
+    has no tiling rule, and a block of 8 keeps sparse cells' padding small.
+    """
+    from repro.kernels.ops import pallas_interpret_default
+
+    return 8 if pallas_interpret_default() else 128
+
+
 def auto_cell_block(n_users: int, n_occupied_cells: int) -> int:
     """Pick the per-cell user block size for a bucketing.
 
     Every occupied cell pads its user run up to a block multiple, so the
     padded total is ~``n + occupied * block``: a block near the mean cell
-    occupancy keeps the waste bounded at ~2x while staying sublane-aligned
-    (multiples of 8) for the TPU layout.  Clamped to [8, 256].
+    occupancy keeps the waste bounded at ~2x.  A power of two clamped to
+    ``[min_cell_block(), 256]``: [128, 256] on the compiled path, [8, 256]
+    in interpret mode.
     """
     occ = max(int(n_occupied_cells), 1)
     mean = max(int(np.ceil(n_users / occ)), 1)
-    return int(min(256, max(8, 1 << int(np.ceil(np.log2(mean))))))
+    return int(min(256, max(min_cell_block(), 1 << int(np.ceil(np.log2(mean))))))
 
 
 def measured_pad_waste(xs, ys, rect, G: int) -> float:
@@ -213,71 +234,6 @@ def unsort_cell_counts(counts: np.ndarray, order: np.ndarray, n: int) -> np.ndar
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _grid_raycast_cells_call(
-    xs_sorted, ys_sorted, cell_map, base, planes, *, block: int, interpret: bool
-):
-    n_blocks = int(cell_map.shape[0])
-    L = planes.shape[-1]
-
-    def kernel(cell_map_ref, base_ref, x_ref, y_ref, p_ref, o_ref):
-        x = x_ref[...][:, None]  # [BU, 1]
-        y = y_ref[...][:, None]
-        p = p_ref[0]  # [3, 3, L] — (edge, coeff, tri)
-        inside = (x * p[0, 0][None, :] + y * p[0, 1][None, :] + p[0, 2][None, :]) >= 0.0
-        inside &= (x * p[1, 0][None, :] + y * p[1, 1][None, :] + p[1, 2][None, :]) >= 0.0
-        inside &= (x * p[2, 0][None, :] + y * p[2, 1][None, :] + p[2, 2][None, :]) >= 0.0
-        i = pl.program_id(0)
-        o_ref[...] = jnp.sum(inside, axis=1, dtype=jnp.int32) + base_ref[cell_map_ref[i]]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # cell_map, base
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i, cm, bs: (i,)),
-            pl.BlockSpec((block,), lambda i, cm, bs: (i,)),
-            pl.BlockSpec((1, 3, 3, L), lambda i, cm, bs: (cm[i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i, cm, bs: (i,)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blocks * block,), jnp.int32),
-        compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(cell_map, base, xs_sorted, ys_sorted, planes)
-
-
-def grid_raycast_cells(
-    xs_sorted,
-    ys_sorted,
-    cell_map,
-    base,
-    planes,
-    *,
-    block: int = 256,
-    interpret: bool | None = None,
-):
-    """Bucketed grid hit counting (single query).
-
-    ``xs_sorted/ys_sorted``: ``[n_blocks*block]`` f32 (cell-sorted, padded);
-    ``cell_map``: ``[n_blocks]`` int32; ``base``: ``[G*G]`` int32;
-    ``planes``: ``[G*G, 3, 3, L]`` from :func:`pack_cell_coeff_planes`.
-    Returns counts ``[n_blocks*block]`` int32 (sorted order).
-    ``interpret=None`` auto-detects like every wrapper in
-    :mod:`repro.kernels.ops` — a real TPU runs the compiled Mosaic kernel.
-    """
-    if interpret is None:
-        from repro.kernels.ops import pallas_interpret_default
-
-        interpret = pallas_interpret_default()
-    return _grid_raycast_cells_call(
-        xs_sorted, ys_sorted, cell_map, base, planes,
-        block=block, interpret=bool(interpret),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def grid_raycast_cells_batch(
     xs_sorted, ys_sorted, cell_map, planes, *, block: int, interpret: bool
 ):
@@ -292,34 +248,37 @@ def grid_raycast_cells_batch(
     ``[Q, G*G]`` scalars would not fit the prefetch budget at serving Q).
     """
     n_blocks = int(cell_map.shape[0])
+    n_sorted = n_blocks * block
     q_n = int(planes.shape[0])
     L = planes.shape[-1]
 
     def kernel(cell_map_ref, x_ref, y_ref, p_ref, o_ref):
-        x = x_ref[...][:, None]  # [BU, 1]
-        y = y_ref[...][:, None]
+        x = x_ref[0][:, None]  # [BU, 1]
+        y = y_ref[0][:, None]
         p = p_ref[0, 0]  # [3, 3, L]
         inside = (x * p[0, 0][None, :] + y * p[0, 1][None, :] + p[0, 2][None, :]) >= 0.0
         inside &= (x * p[1, 0][None, :] + y * p[1, 1][None, :] + p[1, 2][None, :]) >= 0.0
         inside &= (x * p[2, 0][None, :] + y * p[2, 1][None, :] + p[2, 2][None, :]) >= 0.0
-        o_ref[0, :] = jnp.sum(inside, axis=1, dtype=jnp.int32)
+        o_ref[...] = jnp.sum(inside, axis=1, dtype=jnp.int32)[None, None, :]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # cell_map
         grid=(q_n, n_blocks),
         in_specs=[
-            pl.BlockSpec((block,), lambda i, j, cm: (j,)),
-            pl.BlockSpec((block,), lambda i, j, cm: (j,)),
+            pl.BlockSpec((1, block), lambda i, j, cm: (0, j)),
+            pl.BlockSpec((1, block), lambda i, j, cm: (0, j)),
             pl.BlockSpec((1, 1, 3, 3, L), lambda i, j, cm: (i, cm[j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda i, j, cm: (i, j)),
+        out_specs=pl.BlockSpec((1, 1, block), lambda i, j, cm: (i, 0, j)),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((q_n, n_blocks * block), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((q_n, 1, n_sorted), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(cell_map, xs_sorted, ys_sorted, planes)
+    )(
+        cell_map, xs_sorted.reshape(1, n_sorted), ys_sorted.reshape(1, n_sorted), planes
+    ).reshape(q_n, n_sorted)

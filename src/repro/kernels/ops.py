@@ -1,18 +1,18 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles padding to tile multiples, layout packing (``[M,3,3]`` coeffs →
-``A/B/C`` planes), backend selection and unpadding.  On this CPU container
-the kernels execute in interpret mode (bit-faithful to the TPU lowering's
-semantics); on a real TPU set ``REPRO_PALLAS_INTERPRET=0`` (or rely on the
-auto-detection) to run the compiled Mosaic kernels.  ``backend="ref"``
-routes to the pure-jnp oracle — the fast path on CPU and the baseline the
-kernels are benchmarked against.
+``A/B/C`` planes), backend selection and unpadding.  Where JAX's default
+backend is a TPU the kernels run compiled by Mosaic; on any other backend
+they run in interpret mode (bit-faithful to the TPU lowering's
+semantics) — :func:`pallas_interpret_default` decides, from
+``jax.default_backend()`` alone.  ``backend="ref"`` routes to the
+pure-jnp oracle — the fast path on CPU and the baseline the kernels are
+benchmarked against.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import numpy as np
 
@@ -20,12 +20,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
-from repro.obs.jitmon import track_jit
+from repro.kernels.grid_raycast import grid_raycast_cells_batch
+from repro.kernels.rank_count import rank_count_kernel_call
 from repro.kernels.raycast import (
     raycast_count_batch_kernel_call,
     raycast_count_kernel_call,
 )
-from repro.kernels.rank_count import rank_count_kernel_call
+from repro.obs.jitmon import track_jit
 
 __all__ = [
     "raycast_count",
@@ -59,9 +60,7 @@ def _rank_ref_jit(xs, ys, fx, fy, thr):
 
 
 def pallas_interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+    """True unless JAX's default backend is a TPU (compiled Mosaic kernels)."""
     return jax.default_backend() != "tpu"
 
 
@@ -77,7 +76,9 @@ def _effective_blocks(n: int, m: int, bu: int, bm: int) -> tuple[int, int]:
     """Shrink tile sizes to the pow2 envelope of the problem.
 
     Shared by the single-query and batched wrappers so their layouts can't
-    drift apart."""
+    drift apart.  A problem smaller than a tile becomes one whole-array
+    block; a larger one keeps the requested tile, so the default
+    ``bu = 1024`` satisfies the compiled kernels' 1-D tiling rule."""
     bu_eff = min(bu, max(8, 1 << max(int(np.ceil(np.log2(max(n, 1)))), 3)))
     bm_eff = min(bm, max(128, 1 << max(int(np.ceil(np.log2(max(m, 1)))), 7)))
     return bu_eff, bm_eff
@@ -260,8 +261,6 @@ def grid_count_cells_batch(
     Counts stay in sorted order — unscatter with
     :func:`repro.kernels.grid_raycast.unsort_cell_counts`.
     """
-    from repro.kernels.grid_raycast import grid_raycast_cells_batch
-
     xs_sorted = jnp.asarray(xs_sorted, jnp.float32)
     ys_sorted = jnp.asarray(ys_sorted, jnp.float32)
     base = jnp.asarray(base, jnp.int32)
@@ -336,7 +335,7 @@ def rank_count(
     exclude: int | None = None,
     backend: str = "pallas",
     bu: int = 1024,
-    bm: int = 512,
+    bm: int = 1024,
     interpret: bool | None = None,
 ):
     """#facilities strictly closer than ``q`` per user (``[N]`` int32).
@@ -406,11 +405,18 @@ def rank_count_batch(users, facilities, q_pts, *, exclude=None):
 
 
 # ---------------------------------------------------------------------------
-# compile accounting: every module-level jitted reference entry point is
+# compile accounting: every jitted kernel and reference entry point is
 # wrapped so an unexpected retrace (a pad-bucket miss storm reshaping the
-# dense oracle, a chunk-size change) surfaces as ``compile.count{fn=...}``
-# in the process metrics registry instead of a mystery latency spike.
+# dense oracle, a chunk-size change, a new cell-block count) surfaces as
+# ``compile.count{fn=...}`` in the process metrics registry instead of a
+# mystery latency spike.
 # ---------------------------------------------------------------------------
+raycast_count_kernel_call = track_jit(raycast_count_kernel_call, "raycast_kernel")
+raycast_count_batch_kernel_call = track_jit(
+    raycast_count_batch_kernel_call, "raycast_batch_kernel"
+)
+rank_count_kernel_call = track_jit(rank_count_kernel_call, "rank_kernel")
+grid_raycast_cells_batch = track_jit(grid_raycast_cells_batch, "grid_cells_batch_kernel")
 _raycast_ref_chunked = track_jit(_raycast_ref_chunked, "raycast_ref")
 _rank_ref_jit = track_jit(_rank_ref_jit, "rank_ref")
 _raycast_batch_ref_jit = track_jit(_raycast_batch_ref_jit, "raycast_batch_ref")

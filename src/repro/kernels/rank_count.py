@@ -9,10 +9,12 @@ user, the number of facilities strictly closer than the query facility:
 
 It shares the tiling scheme of :mod:`repro.kernels.raycast` (users on the
 first grid axis, facilities lane-tiled on the second, int32 accumulator
-revisited across facility blocks).  It doubles as the *exact* RkNN oracle
-on device: ``count[u] < k ⇔ u ∈ RkNN(q)``, which makes it both the
-correctness anchor for the ray-cast kernels and the measured no-RT baseline
-in ``benchmarks/bench_no_rt.py``.
+revisited across facility blocks).  Both operands are 1-D here, so on
+the compiled path each block is one ``T(1024)`` tile or a whole array.
+It doubles as the *exact* RkNN oracle on device: ``count[u] < k ⇔ u ∈
+RkNN(q)``, which makes it both the correctness anchor for the ray-cast
+kernels and the measured no-RT baseline in
+``benchmarks/bench_no_rt.py``.
 """
 
 from __future__ import annotations
@@ -22,13 +24,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["rank_count_kernel_call", "DEFAULT_BU", "DEFAULT_BM"]
 
 DEFAULT_BU = 1024
-DEFAULT_BM = 512
+DEFAULT_BM = 1024  # facilities ride 1-D blocks too: one T(1024) tile
 
 
 def _rank_kernel(x_ref, y_ref, fx_ref, fy_ref, t_ref, o_ref):
@@ -49,7 +50,7 @@ def _rank_kernel(x_ref, y_ref, fx_ref, fy_ref, t_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bu", "bm", "interpret"))
 def rank_count_kernel_call(
-    xs, ys, fx, fy, thr, *, bu: int = DEFAULT_BU, bm: int = DEFAULT_BM, interpret: bool = True
+    xs, ys, fx, fy, thr, *, bu: int = DEFAULT_BU, bm: int = DEFAULT_BM, interpret: bool
 ):
     """Pre-padded invoke: ``xs, ys, thr`` are ``[Np]``; ``fx, fy`` are
     ``[Mp]`` with padding facilities pushed to +inf (never closer)."""
@@ -68,7 +69,7 @@ def rank_count_kernel_call(
         ],
         out_specs=pl.BlockSpec((bu,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_p,), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

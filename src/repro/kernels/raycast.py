@@ -25,10 +25,16 @@ InfZone-style pruning the scene is so small (``m`` ≈ 40–70) that the sweep
 is *user-read bound*, not test bound — see EXPERIMENTS.md §Perf-RkNN for
 the measured arithmetic-intensity argument.
 
-VMEM budget at the default tiles (BU=1024, BM=512): x/y blocks 8 KiB,
-coefficient blocks 3·2·6 KiB, the ``[BU, BM]`` f32 broadcast temps
-~2 MiB×3 live — comfortably under the ~16 MiB/core budget with double
-buffering.
+Tiling rules of the compiled (Mosaic) kernels: a block's last two
+dimensions must be multiples of ``(8, 128)`` or span the whole array.
+The 1-D user blocks therefore take ``BU = 1024`` (one XLA ``T(1024)``
+tile) or the whole padded user array (``_effective_blocks`` in
+:mod:`repro.kernels.ops` picks one of the two); the batched kernel writes
+a ``[Q, 1, Np]`` output through ``(1, 1, BU)`` blocks, whose unit middle
+axis spans its whole dimension.  VMEM at the default tiles (BU=1024,
+BM=512): x/y blocks 8 KiB, coefficient blocks 3·2·6 KiB, the
+``[BU, BM]`` f32 broadcast temps ~2 MiB×3 live — under the ~16 MiB/core
+budget with double buffering.
 """
 
 from __future__ import annotations
@@ -38,8 +44,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "raycast_count_kernel_call",
@@ -48,7 +53,7 @@ __all__ = [
     "DEFAULT_BM",
 ]
 
-DEFAULT_BU = 1024  # users per block (8·128 sublane-aligned once reshaped)
+DEFAULT_BU = 1024  # users per block (one T(1024) tile of a 1-D array)
 DEFAULT_BM = 512  # occluders per block (4 lanes of 128)
 
 
@@ -73,7 +78,7 @@ def _raycast_kernel(x_ref, y_ref, a_ref, b_ref, c_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bu", "bm", "interpret"))
 def raycast_count_kernel_call(
-    xs, ys, A, B, C, *, bu: int = DEFAULT_BU, bm: int = DEFAULT_BM, interpret: bool = True
+    xs, ys, A, B, C, *, bu: int = DEFAULT_BU, bm: int = DEFAULT_BM, interpret: bool
 ):
     """Invoke the kernel on pre-padded inputs.
 
@@ -97,7 +102,7 @@ def raycast_count_kernel_call(
         ],
         out_specs=pl.BlockSpec((bu,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_p,), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -125,12 +130,12 @@ def _raycast_batch_kernel(x_ref, y_ref, a_ref, b_ref, c_ref, o_ref):
     inside = (x * a[0][None, :] + y * b[0][None, :] + c[0][None, :]) >= 0.0
     inside &= (x * a[1][None, :] + y * b[1][None, :] + c[1][None, :]) >= 0.0
     inside &= (x * a[2][None, :] + y * b[2][None, :] + c[2][None, :]) >= 0.0
-    o_ref[...] += jnp.sum(inside, axis=1, dtype=jnp.int32)[None, :]
+    o_ref[...] += jnp.sum(inside, axis=1, dtype=jnp.int32)[None, None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("bu", "bm", "interpret"))
 def raycast_count_batch_kernel_call(
-    xs, ys, A, B, C, *, bu: int = DEFAULT_BU, bm: int = DEFAULT_BM, interpret: bool = True
+    xs, ys, A, B, C, *, bu: int = DEFAULT_BU, bm: int = DEFAULT_BM, interpret: bool
 ):
     """Batched multi-query invoke on pre-padded inputs.
 
@@ -138,6 +143,8 @@ def raycast_count_batch_kernel_call(
     ``[Q, 3, Mp]`` per-query edge-coefficient planes (``Mp % bm == 0``,
     padding degenerate).  Returns ``[Q, Np]`` int32 counts — one kernel
     dispatch for the whole query batch instead of ``Q`` separate launches.
+    The kernel writes ``[Q, 1, Np]`` (a ``(1, BU)`` block of a ``[Q, Np]``
+    array breaks the tiling rule) and the unit axis is dropped here.
     """
     n_p = xs.shape[0]
     q_n, _, m_p = A.shape
@@ -152,10 +159,10 @@ def raycast_count_batch_kernel_call(
             pl.BlockSpec((1, 3, bm), lambda q, i, j: (q, 0, j)),
             pl.BlockSpec((1, 3, bm), lambda q, i, j: (q, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bu), lambda q, i, j: (q, i)),
-        out_shape=jax.ShapeDtypeStruct((q_n, n_p), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        out_specs=pl.BlockSpec((1, 1, bu), lambda q, i, j: (q, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((q_n, 1, n_p), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(xs, ys, A, B, C)
+    )(xs, ys, A, B, C).reshape(q_n, n_p)

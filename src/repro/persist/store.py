@@ -425,11 +425,15 @@ def _adopt_indexes(engine, snap, meta, arrays, scenes: list[Scene]) -> int:
 def _adopt_kernel(engine, snap, meta, arrays) -> int:
     import jax.numpy as jnp
 
+    from repro.kernels.grid_raycast import min_cell_block
+
     xs = snap.xs  # materializes the live device arrays the key pins
     n_adopted = 0
     for i, ent in enumerate(meta["entries"]):
         if int(ent["n"]) != int(xs.shape[0]):
             continue
+        if int(ent["block"]) % min_cell_block():
+            continue  # bucketed in interpret mode, too fine for the compiled kernel
         buckets = (
             jnp.asarray(arrays[f"b{i}_xs_s"]),
             jnp.asarray(arrays[f"b{i}_ys_s"]),

@@ -54,17 +54,16 @@ def est_pad_waste(n_users: int, grid_g: int = 64) -> float:
     """Pre-measurement estimate of the cell-bucketing pad-waste ratio.
 
     Assumes uniformly spread users: ``min(G², |U|)`` occupied cells, so
-    the mean occupancy and :func:`repro.kernels.grid_raycast.
-    auto_cell_block`'s [8, 256] power-of-two clamp give ``padded ≈
-    occupied · block``.  A pure function of |U| (perfectly collinear with
-    ``log_u``), so fits that only ever see this fallback must ``drop``
-    the ``log_pw`` feature; calibration passes the *measured* ratio of
-    the actual workload instead."""
+    the block :func:`repro.kernels.grid_raycast.auto_cell_block` picks
+    for them gives ``padded ≈ occupied · block``.  A pure function of |U|
+    (perfectly collinear with ``log_u``), so fits that only ever see this
+    fallback must ``drop`` the ``log_pw`` feature; calibration passes the
+    *measured* ratio of the actual workload instead."""
+    from repro.kernels.grid_raycast import auto_cell_block
+
     u = max(int(n_users), 1)
     occ = min(int(grid_g) * int(grid_g), u)
-    mean = max(int(np.ceil(u / occ)), 1)
-    block = int(min(256, max(8, 1 << int(np.ceil(np.log2(mean))))))
-    return max(occ * block / u, 1.0)
+    return max(occ * auto_cell_block(u, occ) / u, 1.0)
 
 
 @dataclasses.dataclass(frozen=True)

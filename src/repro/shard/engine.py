@@ -147,6 +147,11 @@ class ShardState:
         )
 
 
+def _device_of(out: jax.Array) -> str:
+    """The device a shard's kernel output landed on."""
+    return str(next(iter(out.devices())))
+
+
 def _spatial_perm(users: np.ndarray, rect: Rect, grid_g: int) -> np.ndarray:
     """Stable sort of user rows by grid cell id — the same ``cx*G + cy``
     the bucketed kernels use, so each contiguous cut covers a compact
@@ -262,6 +267,7 @@ class ShardDispatch:
         out_t: np.ndarray | None = None
         t_verify = [0.0] * state.n_shards
         partials: list[np.ndarray | None] = [None] * state.n_shards
+        out_devices: list[str | None] = [None] * state.n_shards
         for i, view in enumerate(state.views):
             if view.n_users == 0:
                 continue
@@ -269,12 +275,12 @@ class ShardDispatch:
             sv.__enter__()
             if kind == "shard":
                 xs_s, ys_s, dest, ok, ranks, block, base_q, planes_q = payload[i]
-                counts = np.asarray(
-                    _ops.grid_count_cells_batch(
-                        xs_s, ys_s, ranks, base_q, planes_q,
-                        block=block, backend=backend.kernel_backend,
-                    )
+                counts = _ops.grid_count_cells_batch(
+                    xs_s, ys_s, ranks, base_q, planes_q,
+                    block=block, backend=backend.kernel_backend,
                 )
+                out_devices[view.index] = _device_of(counts)
+                counts = np.asarray(counts)
                 if out_t is None:
                     out_t = np.zeros(
                         (state.n_users + 1, counts.shape[0]), np.int32
@@ -283,34 +289,29 @@ class ShardDispatch:
                 part = ((counts < self.k) & ok).sum(axis=1).astype(np.int64)
             else:
                 if name in _DENSE_BACKENDS:
-                    slab = np.asarray(
-                        _ops.raycast_count_batch(
-                            view.xs, view.ys, payload,
-                            backend=backend.kernel_backend,
-                        )
+                    slab = _ops.raycast_count_batch(
+                        view.xs, view.ys, payload,
+                        backend=backend.kernel_backend,
                     )
                 elif name in _GRID_BACKENDS:
                     from repro.core.grid import grid_hit_counts_batch_jnp
 
                     base, lists, coeffs = payload
-                    slab = np.asarray(
-                        grid_hit_counts_batch_jnp(
-                            view.xs, view.ys, base, lists, coeffs,
-                            self.rect, self.engine.config.grid_g,
-                        )
+                    slab = grid_hit_counts_batch_jnp(
+                        view.xs, view.ys, base, lists, coeffs,
+                        self.rect, self.engine.config.grid_g,
                     )
                 elif name in _BVH_BACKENDS:
                     from repro.core.bvh import bvh_hit_counts_batch
 
                     left, right, bbox, coeffs = payload
-                    slab = np.asarray(
-                        bvh_hit_counts_batch(
-                            view.xs, view.ys, left, right, bbox, coeffs,
-                            k=self.k,
-                        )
+                    slab = bvh_hit_counts_batch(
+                        view.xs, view.ys, left, right, bbox, coeffs, k=self.k
                     )
                 else:  # pragma: no cover — _mesh_dispatch_for gates the names
                     raise ValueError(f"unshardable backend {name!r}")
+                out_devices[view.index] = _device_of(slab)
+                slab = np.asarray(slab)
                 if out_t is None:
                     out_t = np.zeros(
                         (state.n_users + 1, slab.shape[0]), np.int32
@@ -331,6 +332,7 @@ class ShardDispatch:
             backend=name,
             version=state.version,
             per_shard_users=[v.n_users for v in state.views],
+            per_shard_device=out_devices,
             sizes=sizes,
         )
         return out_t[: state.n_users].T
@@ -341,11 +343,13 @@ class ShardedEngine(DynamicEngine):
     is partitioned over a user-axis device mesh.
 
     Construction adds the mesh knobs; every query/update surface is
-    inherited.  ``shards`` cycles the visible devices when the host has
-    fewer (the partition and compaction are preserved; only physical
-    parallelism collapses), or pass ``mesh=user_mesh(n)`` for a strict
-    one-device-per-shard layout.  Masks and counts are bit-identical to
-    the single-process engine for every concrete backend.
+    inherited.  Pass ``mesh=user_mesh(n)`` for a strict one-device-per-
+    shard layout.  ``shards`` alone takes one visible device per shard;
+    CPU devices are cycled when there are fewer (the partition and
+    compaction are preserved; only physical parallelism collapses), while
+    accelerators raise — a shard never silently shares a chip.  Masks and
+    counts are bit-identical to the single-process engine for every
+    concrete backend.
     """
 
     def __init__(
@@ -512,7 +516,7 @@ class ShardedEngine(DynamicEngine):
             self._shard_hist("filter", i).observe(t)
 
     def _note_shard_verify(
-        self, times, *, backend, version, per_shard_users, sizes
+        self, times, *, backend, version, per_shard_users, per_shard_device, sizes
     ) -> None:
         tot = [0.0] * self.n_shards
         for i, t in enumerate(times):
@@ -532,6 +536,7 @@ class ShardedEngine(DynamicEngine):
                 "version": version,
                 "shards": self.n_shards,
                 "per_shard_users": list(per_shard_users),
+                "per_shard_device": list(per_shard_device),
                 "per_shard_verify_s": [float(t) for t in times],
                 "imbalance": imbalance,
                 "result_sizes": [int(x) for x in np.asarray(sizes)],

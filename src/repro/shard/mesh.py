@@ -10,11 +10,12 @@ On a development box the mesh is synthetic: launch with
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4
 
-and :func:`user_mesh` sees four ``CpuDevice``s.  Without the flag (or on
-a box with fewer devices than shards) :func:`shard_devices` degrades
-gracefully by cycling the available devices — the partition, the
-per-shard compaction, and the bit-identical reduction are all preserved;
-only the physical parallelism collapses onto the shared device.
+and :func:`user_mesh` sees four ``CpuDevice``s.  Without the flag
+:func:`shard_devices` cycles the available CPU devices — the partition,
+the per-shard compaction, and the bit-identical reduction are all
+preserved; only the physical parallelism collapses onto the shared
+device.  Accelerators are never cycled: asking for more shards than
+chips raises.
 """
 
 from __future__ import annotations
@@ -60,8 +61,10 @@ def mesh_shards(mesh: Mesh) -> int:
 
 def shard_devices(n_shards: int, mesh: Mesh | None = None) -> list:
     """One device per shard.  From a mesh: its ``'users'`` axis devices.
-    Without one: the visible devices, cycled when there are fewer devices
-    than shards (single-device boxes still run every shard count)."""
+    Without one: the visible devices.  CPU devices are cycled when there
+    are fewer than shards (a development box still runs every shard
+    count); accelerators raise instead, so a shard never shares a chip
+    without saying so."""
     if mesh is not None:
         devs = list(mesh.devices.reshape(-1))
         if len(devs) != n_shards:
@@ -70,4 +73,10 @@ def shard_devices(n_shards: int, mesh: Mesh | None = None) -> list:
             )
         return devs
     devs = jax.devices()
-    return [devs[i % len(devs)] for i in range(int(n_shards))]
+    n = int(n_shards)
+    if n > len(devs) and devs[0].platform != "cpu":
+        raise ValueError(
+            f"{n} shards requested but only {len(devs)} {devs[0].platform} "
+            "device(s) visible"
+        )
+    return [devs[i % len(devs)] for i in range(n)]

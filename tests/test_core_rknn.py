@@ -10,11 +10,7 @@
 
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # tier-1 environment: replay over a fixed seed sweep
-    from tests._hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.brute import rknn_brute_np, rknn_mono_brute_np
 from repro.core.bvh import build_bvh, bvh_hit_counts

@@ -7,11 +7,7 @@ half-plane.  Plus edge-function/clip/area unit checks.
 
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # tier-1 environment: replay over a fixed seed sweep
-    from tests._hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.geometry import (
     Rect,

@@ -116,11 +116,7 @@ def test_grid_raycast_kernel_matches_f32_reference(G, block):
     """Grid Pallas kernel == dense f32 reference.  (Comparison is f32-to-f32:
     the f64 host oracle can differ by measure-zero edge-test ties.)"""
     from repro.core.grid import build_grid
-    from repro.kernels.grid_raycast import (
-        grid_raycast_cells,
-        pack_cell_coeff_planes,
-        prepare_cell_buckets,
-    )
+    from repro.kernels.grid_raycast import pack_cell_coeff_planes, prepare_cell_buckets
 
     sc, U = _nonpruned_scene(G * 1000 + block)
     ref32 = np.asarray(
@@ -130,7 +126,9 @@ def test_grid_raycast_kernel_matches_f32_reference(G, block):
     xs_s, ys_s, order, cell_map, nb = prepare_cell_buckets(U[:, 0], U[:, 1], RECT, G, block=block)
     planes = pack_cell_coeff_planes(g)
     counts = np.asarray(
-        grid_raycast_cells(xs_s, ys_s, cell_map, g.base, planes, block=block, interpret=True)
+        ops.grid_count_cells(
+            xs_s, ys_s, cell_map, g.base, planes, block=block, interpret=True
+        )
     )
     ok = order >= 0
     got = np.zeros(len(U), np.int64)
@@ -202,13 +200,18 @@ def test_prepare_cell_buckets_perf_shape():
     assert dt < 2.0, f"bucketing took {dt:.2f}s — host rescan regression?"
 
 
-def test_auto_cell_block_tracks_occupancy():
+def test_auto_cell_block_tracks_occupancy(monkeypatch):
     from repro.kernels.grid_raycast import auto_cell_block
 
     assert auto_cell_block(100, 100) == 8  # sparse cells: minimal block
     assert auto_cell_block(4096, 16) == 256  # dense cells: capped at 256
     assert auto_cell_block(1000, 30) == 64  # mean 34 -> next pow2, clamped
     assert auto_cell_block(0, 0) == 8
+    # the compiled kernel's lane-dimension blocks start at one 128-lane tile
+    monkeypatch.setattr(ops, "pallas_interpret_default", lambda: False)
+    assert auto_cell_block(100, 100) == 128
+    assert auto_cell_block(1000, 30) == 128
+    assert auto_cell_block(4096, 16) == 256
 
 
 @pytest.mark.parametrize("Q", [1, 3])
@@ -263,11 +266,7 @@ def test_grid_raycast_cells_interpret_autodetect():
     real TPU would run the compiled Mosaic kernel) and matches the
     explicit interpret=True result on this CPU container."""
     from repro.core.grid import build_grid
-    from repro.kernels.grid_raycast import (
-        grid_raycast_cells,
-        pack_cell_coeff_planes,
-        prepare_cell_buckets,
-    )
+    from repro.kernels.grid_raycast import pack_cell_coeff_planes, prepare_cell_buckets
 
     assert ops.pallas_interpret_default()  # CPU container: interpret is on
     sc, U = _nonpruned_scene(3, n_fac=80)
@@ -277,10 +276,10 @@ def test_grid_raycast_cells_interpret_autodetect():
     )
     planes = pack_cell_coeff_planes(g)
     auto = np.asarray(
-        grid_raycast_cells(xs_s, ys_s, cell_map, g.base, planes, block=128)
+        ops.grid_count_cells(xs_s, ys_s, cell_map, g.base, planes, block=128)
     )
     explicit = np.asarray(
-        grid_raycast_cells(
+        ops.grid_count_cells(
             xs_s, ys_s, cell_map, g.base, planes, block=128, interpret=True
         )
     )

@@ -255,6 +255,36 @@ def test_expected_fingerprints_move_with_data():
     assert moved["planner"] == base["planner"]  # data-independent
 
 
+def test_interpret_mode_bucketing_not_adopted_by_compiled_kernel(
+    tmp_path, monkeypatch
+):
+    """A store bucketed in interpret mode (8-user cell blocks) is restored
+    on a runtime whose compiled kernel needs 128-user blocks: the stale
+    bucketing is skipped and rebuilt, and answers stay identical."""
+    import repro.kernels.grid_raycast as gr
+
+    F, U, _ = _instance(seed=31)
+    cfg = RkNNConfig(backend="grid-pallas-ref", grid_g=16)
+    eng = RkNNEngine(F, U, cfg)
+    want = eng.query_batch([0, 3], 6)
+    d = str(tmp_path / "store")
+    eng.save_state(d)
+
+    monkeypatch.setattr(gr, "min_cell_block", lambda: 128)
+    warm = RkNNEngine(F, U, RkNNConfig(backend="grid-pallas-ref", grid_g=16, warm_store=d))
+    assert not any(
+        key[0] == "gp-buckets" for key, _ in warm._snap.kernel_memo.items()
+    )
+    got = warm.query_batch([0, 3], 6)
+    np.testing.assert_array_equal(got.counts, want.counts)
+    blocks = [
+        value[1][5]
+        for key, value in warm._snap.kernel_memo.items()
+        if key[0] == "gp-buckets"
+    ]
+    assert blocks and all(b % 128 == 0 for b in blocks)
+
+
 # --------------------------------------------------------- MVCC hot-adopt
 def test_hot_adopt_publishes_next_version(tmp_path):
     F, U, rng = _instance(seed=23)

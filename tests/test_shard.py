@@ -158,6 +158,10 @@ def test_per_shard_stats_and_explain():
     assert rec["shards"] == 4
     assert sum(rec["per_shard_users"]) == len(U)
     assert len(rec["per_shard_verify_s"]) == 4
+    # the device each shard's kernel output landed on
+    assert rec["per_shard_device"] == [
+        str(v.device) for v in eng._snap.shard_state.views
+    ]
     # psum-reduced result sizes match the actual masks
     got = eng.query_batch(qs, K)
     recs2 = [e for e in eng.explain() if e.get("mode") == "shard-batch"]
@@ -221,7 +225,7 @@ def test_user_mesh_and_devices():
     mesh = user_mesh(n_dev)
     assert mesh_shards(mesh) == n_dev
     assert shard_devices(n_dev, mesh) == list(jax.devices())
-    # oversubscription cycles without a mesh, errors with one
+    # oversubscription cycles CPU devices without a mesh, errors with one
     devs = shard_devices(n_dev + 3)
     assert len(devs) == n_dev + 3
     with pytest.raises(ValueError):
@@ -232,3 +236,22 @@ def test_user_mesh_and_devices():
     assert eng.n_shards == n_dev
     with pytest.raises(ValueError):
         ShardedEngine(F, U, backend="dense-ref", mesh=mesh, shards=n_dev + 1)
+
+
+def test_shard_devices_never_cycle_accelerators(monkeypatch):
+    """Asking for more shards than chips raises instead of stacking two
+    shards on one accelerator; exactly as many shards as chips is fine."""
+    import jax
+
+    import repro.shard.mesh as shard_mesh
+
+    class _Chip:
+        platform = "tpu"
+
+    chips = [_Chip(), _Chip()]
+    monkeypatch.setattr(shard_mesh.jax, "devices", lambda: chips)
+    assert shard_devices(2) == chips
+    with pytest.raises(ValueError, match="2 tpu device"):
+        shard_devices(3)
+    monkeypatch.undo()
+    assert len(shard_devices(len(jax.devices()) + 1)) == len(jax.devices()) + 1
