@@ -47,6 +47,7 @@ from typing import Any, Callable, ClassVar
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.bvh import (
@@ -74,6 +75,7 @@ from repro.kernels.grid_raycast import (
     repack_cell_coeff_planes,
     unsort_cell_counts,
 )
+from repro.obs import span
 
 __all__ = [
     "Backend",
@@ -147,6 +149,9 @@ class BatchRequest:
     excludes: list[int | None] | None = None
     mp: int | None = None
     dispatch: Callable | None = None
+    #: The engine's ``MetricsRegistry``: the verify phase counts the bytes
+    #: it copies in ``copy.bytes{dir=h2d|d2h}``.  ``None`` counts nothing.
+    metrics: Any = None
     #: Per-snapshot kernel memo — see :attr:`QueryRequest.memo`.
     memo: Any = None
 
@@ -295,6 +300,25 @@ def timeable_backends() -> tuple[str, ...]:
     )
 
 
+def _count_copy(req: BatchRequest, direction: str, nbytes: int) -> None:
+    if req.metrics is not None:
+        req.metrics.counter("copy.bytes", dir=direction).inc(nbytes)
+
+
+def _to_host(counts, req: BatchRequest) -> np.ndarray:
+    """The copy back of one batched count, which every ``count_batch``
+    goes through: ``verify.wait`` until the device result is ready, then
+    ``verify.d2h`` (attribute ``bytes``) for the copy to the host.  The
+    wait is the one the copy would make anyway."""
+    with span("verify.wait"):
+        jax.block_until_ready(counts)
+    nbytes = int(counts.nbytes)
+    with span("verify.d2h", bytes=nbytes):
+        host = np.asarray(counts)
+    _count_copy(req, "d2h", nbytes)
+    return host
+
+
 # --------------------------------------------------------------------------
 # Dense (stacked edge functions, no index)
 # --------------------------------------------------------------------------
@@ -337,11 +361,14 @@ class DenseBackend(Backend):
 
     def count_batch(self, req: BatchRequest, prepared) -> np.ndarray:
         if req.dispatch is not None:
-            return np.asarray(req.dispatch(prepared))
-        return np.asarray(
+            return _to_host(req.dispatch(prepared), req)
+        if isinstance(prepared, np.ndarray):
+            _count_copy(req, "h2d", prepared.nbytes)
+        return _to_host(
             _ops.raycast_count_batch(
                 req.xs, req.ys, prepared, backend=self.kernel_backend
-            )
+            ),
+            req,
         )
 
 
@@ -466,12 +493,13 @@ class GridBackend(Backend):
 
     def count_batch(self, req: BatchRequest, prepared) -> np.ndarray:
         if req.dispatch is not None:
-            return np.asarray(req.dispatch(prepared))
+            return _to_host(req.dispatch(prepared), req)
         base, lists, coeffs = prepared
-        return np.asarray(
+        return _to_host(
             grid_hit_counts_batch_jnp(
                 req.xs, req.ys, base, lists, coeffs, req.rect, req.grid_g
-            )
+            ),
+            req,
         )
 
 
@@ -704,13 +732,13 @@ class GridPallasBackend(GridBackend):
 
     def count_batch(self, req: BatchRequest, prepared) -> np.ndarray:
         if req.dispatch is not None:
-            return np.asarray(req.dispatch(prepared))
+            return _to_host(req.dispatch(prepared), req)
         xs_s, ys_s, order, ranks, block, base_q, planes_q = prepared
         counts = _ops.grid_count_cells_batch(
             xs_s, ys_s, ranks, base_q, planes_q,
             block=block, backend=self.kernel_backend,
         )
-        return unsort_cell_counts(np.asarray(counts), order, int(req.xs.shape[0]))
+        return unsort_cell_counts(_to_host(counts, req), order, int(req.xs.shape[0]))
 
 
 @register_backend
@@ -791,10 +819,11 @@ class BvhBackend(Backend):
 
     def count_batch(self, req: BatchRequest, prepared) -> np.ndarray:
         if req.dispatch is not None:
-            return np.asarray(req.dispatch(prepared))
+            return _to_host(req.dispatch(prepared), req)
         left, right, bbox, coeffs = prepared
-        return np.asarray(
-            bvh_hit_counts_batch(req.xs, req.ys, left, right, bbox, coeffs, k=req.k)
+        return _to_host(
+            bvh_hit_counts_batch(req.xs, req.ys, left, right, bbox, coeffs, k=req.k),
+            req,
         )
 
 
@@ -816,10 +845,11 @@ class BruteBackend(Backend):
         )
 
     def count_batch(self, req: BatchRequest, prepared) -> np.ndarray:
-        return np.asarray(
+        return _to_host(
             _ops.rank_count_batch(
                 req.users, req.facilities, req.q_pts, exclude=req.excludes
-            )
+            ),
+            req,
         )
 
 

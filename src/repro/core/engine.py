@@ -33,6 +33,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import dataclasses
+import itertools
 import math
 import queue
 import threading
@@ -55,7 +56,14 @@ from repro.core.hybrid import SceneCache, _q_key
 from repro.core.results import RkNNBatchResult, RkNNResult
 from repro.core.scene import Scene, build_scene
 from repro.core.snapshot import EngineSnapshot
-from repro.obs import Histogram, MetricsRegistry, span, track_jit
+from repro.obs import (
+    Histogram,
+    MetricsRegistry,
+    batch_scope,
+    current_batch,
+    span,
+    track_jit,
+)
 from repro.planner.models import WorkloadShape
 
 __all__ = ["RkNNConfig", "EngineStats", "RkNNEngine", "serve_shardings"]
@@ -320,6 +328,8 @@ class RkNNEngine:
         self.metrics = MetricsRegistry()
         self.stats = EngineStats(self.metrics)
         self._init_metrics()
+        # numbers each served batch; every span of a batch carries it
+        self._batch_ids = itertools.count()
         self._snap = self._make_snapshot(
             0,
             np.asarray(facilities, dtype=np.float64),
@@ -401,6 +411,8 @@ class RkNNEngine:
         self._m_pred = m.histogram("planner.plan_s", kind="pred")
         self._m_obs = m.histogram("planner.plan_s", kind="obs")
         self._m_nudges = m.counter("planner.recal_nudges")
+        for direction in ("h2d", "d2h"):  # filled by the verify phase's copies
+            m.counter("copy.bytes", dir=direction)
         self._metric_cache: dict = {}
         m.derived("scene_cache.hit_ratio", self._scene_cache_hit_ratio)
         m.derived("batch_cache.hit_ratio", self._batch_cache_hit_ratio)
@@ -851,8 +863,14 @@ class RkNNEngine:
             return self._build_scene(snap, q, k, rect)
 
         if workers > 0 and len(queries) > 1:
+            batch = current_batch()
+
+            def pooled(q):
+                with batch_scope(batch):  # the worker's spans join the batch
+                    return one(q)
+
             with concurrent.futures.ThreadPoolExecutor(workers) as pool:
-                return list(pool.map(one, queries))
+                return list(pool.map(pooled, queries))
         return [one(q) for q in queries]
 
     def _mp_bucket(self, scenes: list[Scene]) -> int:
@@ -912,9 +930,11 @@ class RkNNEngine:
             excludes=excludes,
             mp=self._mp_bucket(scenes),
             dispatch=dispatch,
+            metrics=self.metrics,
             memo=snap.kernel_memo,
         )
-        prepared = self._prepare_batch(backend, req)
+        with span("filter.stack"):
+            prepared = self._prepare_batch(backend, req)
         self._batch_cache_put(snap, cache_key, (req, prepared, scenes))
         return req, prepared, scenes
 
@@ -1138,8 +1158,10 @@ class RkNNEngine:
             return self._query_batch_planner(snap, b, qs, k, workers)
         queries, q_pts, excludes = _normalize_queries(snap.facilities, qs)
 
+        batch = next(self._batch_ids)
         if not b.uses_scene:
-            with span("batch", backend=b.name, q=len(qs), version=snap.version):
+            with span("batch", backend=b.name, q=len(qs), version=snap.version,
+                      batch=batch):
                 with span("verify", backend=b.name) as sv:
                     counts = b.count_batch(
                         BatchRequest(
@@ -1150,19 +1172,23 @@ class RkNNEngine:
                             facilities=snap.facilities,
                             q_pts=q_pts,
                             excludes=excludes,
+                            metrics=self.metrics,
                         ),
                         None,
                     )
+                with span("mask"):
+                    masks = counts < k
             t_verify = sv.elapsed_s
             self._m_queries.inc(len(qs))
             self._m_batches.inc()
             self._phase_hist("verify", b.name).observe(t_verify)
             self._m_lag.set(float(self._snap.version - snap.version))
             return RkNNBatchResult(
-                counts < k, counts, None, 0.0, t_verify, b.name, k, snap.version
+                masks, counts, None, 0.0, t_verify, b.name, k, snap.version
             )
 
-        with span("batch", backend=b.name, q=len(qs), version=snap.version):
+        with span("batch", backend=b.name, q=len(qs), version=snap.version,
+                  batch=batch):
             with span("filter", backend=b.name) as sf:
                 rect = self._rect_for(snap, q_pts)
                 req, prepared, scenes = self._filter_batch(
@@ -1170,6 +1196,8 @@ class RkNNEngine:
                 )
             with span("verify", backend=b.name) as sv:
                 counts = b.count_batch(req, prepared)
+            with span("mask"):
+                masks = counts < k
         t_filter, t_verify = sf.elapsed_s, sv.elapsed_s
         self._m_queries.inc(len(qs))
         self._m_batches.inc()
@@ -1178,7 +1206,7 @@ class RkNNEngine:
         self._m_mmax.set_max(max(s.n_tris for s in scenes))
         self._m_lag.set(float(self._snap.version - snap.version))
         return RkNNBatchResult(
-            counts < k, counts, scenes, t_filter, t_verify, b.name, k, snap.version
+            masks, counts, scenes, t_filter, t_verify, b.name, k, snap.version
         )
 
     def _dispatch_group(
@@ -1209,6 +1237,7 @@ class RkNNEngine:
                     facilities=snap.facilities,
                     q_pts=q_pts[idxs],
                     excludes=[excludes[i] for i in idxs],
+                    metrics=self.metrics,
                 )
                 prepared = None
             else:
@@ -1247,9 +1276,11 @@ class RkNNEngine:
                     excludes=[excludes[i] for i in idxs],
                     mp=self._mp_bucket(sub),
                     dispatch=dispatch,
+                    metrics=self.metrics,
                     memo=snap.kernel_memo,
                 )
-                prepared = self._prepare_batch(b, req)
+                with span("filter.stack"):
+                    prepared = self._prepare_batch(b, req)
                 self._batch_cache_put(snap, cache_key, (req, prepared, sub))
         with span("verify", backend=b.name, group=1) as sv:
             counts = b.count_batch(req, prepared)
@@ -1278,7 +1309,8 @@ class RkNNEngine:
         """
         queries, q_pts, excludes = _normalize_queries(snap.facilities, qs)
         n_f, n_u, q_n = len(snap.facilities), len(snap.users), len(qs)
-        sb = span("batch", backend="auto", q=q_n, version=snap.version)
+        batch = next(self._batch_ids)
+        sb = span("batch", backend="auto", q=q_n, version=snap.version, batch=batch)
         with sb:
             counts, plan, per_q, groups, scenes, t_count_total = (
                 self._plan_and_dispatch(
@@ -1290,6 +1322,8 @@ class RkNNEngine:
         # device count dispatch (planning, scene builds, group stacking) —
         # same accounting as the old inline perf_counter arithmetic
         t_filter = sb.elapsed_s - t_count_total
+        with span("mask", batch=batch):  # outside `batch`: its wall is filter's
+            masks = counts < k
 
         self._m_queries.inc(q_n)
         self._m_batches.inc()
@@ -1299,7 +1333,7 @@ class RkNNEngine:
             self._m_mmax.set_max(max(s.n_tris for s in scenes))
         self._record_plan(planner, plan, sb.elapsed_s)
         return RkNNBatchResult(
-            counts < k,
+            masks,
             counts,
             scenes,
             t_filter,
@@ -1500,8 +1534,9 @@ class RkNNEngine:
                     # naturally picks up concurrent updates batch to batch
                     snap = self._snap
                     qs = list(batch)
+                    n = next(self._batch_ids)
                     sf = span("filter", backend=b.name, stream=1,
-                              version=snap.version)
+                              version=snap.version, batch=n)
                     sf.__enter__()
                     queries, q_pts, excludes = _normalize_queries(
                         snap.facilities, qs
@@ -1544,12 +1579,13 @@ class RkNNEngine:
                             facilities=snap.facilities,
                             q_pts=q_pts,
                             excludes=excludes,
+                            metrics=self.metrics,
                         )
                         built = (req, None, None)
                     sf.__exit__(None, None, None)
                     t_filter = sf.elapsed_s
                     self._phase_hist("filter", b.name).observe(t_filter)
-                    buf.put((batch, len(qs), b_eff, plan, t_filter, built))
+                    buf.put((n, batch, len(qs), b_eff, plan, t_filter, built))
                 buf.put(None)
             except BaseException as e:  # surface in the consumer, no deadlock
                 buf.put(e)
@@ -1557,15 +1593,18 @@ class RkNNEngine:
         t = threading.Thread(target=producer, daemon=True)
         t.start()
         while True:
-            item = buf.get()
+            with span("stream.wait") as sw:
+                item = buf.get()
+                if isinstance(item, tuple):
+                    sw.batch = item[0]
             if item is None:
                 return
             if isinstance(item, BaseException):
                 if isinstance(item, Exception):
                     self._flight_exception("stream", item)
                 raise item
-            batch, q_n, b_eff, plan, t_filter, (req, prepared, scenes) = item
-            with span("verify", backend=b_eff.name, stream=1) as sv:
+            n, batch, q_n, b_eff, plan, t_filter, (req, prepared, scenes) = item
+            with span("verify", backend=b_eff.name, stream=1, batch=n) as sv:
                 counts = b_eff.count_batch(req, prepared)
             t_verify = sv.elapsed_s
             self._phase_hist("verify", b_eff.name).observe(t_verify)
@@ -1579,4 +1618,6 @@ class RkNNEngine:
                 # time spent waiting in the double buffer and corrupt the
                 # planner's pred-vs-obs calibration signal
                 self._record_plan(b, plan, t_filter + t_verify)
-            yield batch, counts < k
+            with span("mask", batch=n):
+                masks = counts < k
+            yield batch, masks

@@ -16,6 +16,7 @@ import numpy as np
 from repro.core import occluders as occ
 from repro.core.geometry import DEGENERATE_EDGE, Rect
 from repro.core.pruning import PruneStats, prune_facilities
+from repro.obs import span
 
 __all__ = ["Scene", "build_scene", "pad_scene_arrays"]
 
@@ -96,36 +97,41 @@ def build_scene(
     explicit ``[2]`` point.  ``users_hint`` optionally extends the domain
     rectangle so every user is interior.
     """
-    facilities = np.asarray(facilities, dtype=np.float64)
-    if isinstance(q, (int, np.integer)):
-        q_idx: int | None = int(q)
-        q_pt = facilities[q_idx]
-    else:
-        q_idx = None
-        q_pt = np.asarray(q, dtype=np.float64)
-    if rect is None:
-        sets = [facilities, q_pt[None]]
-        if users_hint is not None:
-            sets.append(np.asarray(users_hint, dtype=np.float64))
-        rect = Rect.from_points(*sets)
+    with span("scene.build"):
+        facilities = np.asarray(facilities, dtype=np.float64)
+        if isinstance(q, (int, np.integer)):
+            q_idx: int | None = int(q)
+            q_pt = facilities[q_idx]
+        else:
+            q_idx = None
+            q_pt = np.asarray(q, dtype=np.float64)
+        if rect is None:
+            sets = [facilities, q_pt[None]]
+            if users_hint is not None:
+                sets.append(np.asarray(users_hint, dtype=np.float64))
+            rect = Rect.from_points(*sets)
 
-    keep, stats = prune_facilities(
-        facilities, q_pt, k, rect, strategy=strategy, grid=grid, exclude=q_idx
-    )
-    tris, coeffs, owner = occ.occluders_for_facilities(facilities, q_pt, rect, keep)
-    tris_p, coeffs_p, owner_p, n = pad_scene_arrays(tris, coeffs, owner, pad_to)
-    # paper-faithful distinct layer heights z = 1..T for the kept triangles
-    heights = np.zeros((len(tris_p),), dtype=np.float32)
-    heights[:n] = np.arange(1, n + 1, dtype=np.float32)
-    return Scene(
-        tris=tris_p,
-        coeffs=coeffs_p,
-        owner=owner_p,
-        n_tris=n,
-        n_occluders=int(keep.sum()),
-        keep=keep,
-        q=q_pt,
-        rect=rect,
-        heights=heights,
-        stats=stats,
-    )
+        with span("scene.prune"):
+            keep, stats = prune_facilities(
+                facilities, q_pt, k, rect, strategy=strategy, grid=grid, exclude=q_idx
+            )
+        with span("scene.occluders"):
+            tris, coeffs, owner = occ.occluders_for_facilities(
+                facilities, q_pt, rect, keep
+            )
+            tris_p, coeffs_p, owner_p, n = pad_scene_arrays(tris, coeffs, owner, pad_to)
+        # paper-faithful distinct layer heights z = 1..T for the kept triangles
+        heights = np.zeros((len(tris_p),), dtype=np.float32)
+        heights[:n] = np.arange(1, n + 1, dtype=np.float32)
+        return Scene(
+            tris=tris_p,
+            coeffs=coeffs_p,
+            owner=owner_p,
+            n_tris=n,
+            n_occluders=int(keep.sum()),
+            keep=keep,
+            q=q_pt,
+            rect=rect,
+            heights=heights,
+            stats=stats,
+        )

@@ -26,6 +26,7 @@ from repro.kernels.raycast import (
     raycast_count_batch_kernel_call,
     raycast_count_kernel_call,
 )
+from repro.obs import span
 from repro.obs.jitmon import track_jit
 
 __all__ = [
@@ -175,32 +176,37 @@ def raycast_count_batch(
     :func:`repro.core.scene.pad_scene_arrays`).  Returns ``[Q, N]`` int32.
     ``backend="ref"`` runs the jitted vmap oracle (the fast CPU path);
     ``backend="pallas"`` runs the ``[Q]``-grid-axis kernel.
+
+    Runs in a ``verify.h2d`` span (attribute ``bytes``: the coefficients
+    handed in): the coefficient upload, the planes and pads, and the
+    dispatch.  It returns before the device finishes.
     """
-    xs = jnp.asarray(xs, jnp.float32)
-    ys = jnp.asarray(ys, jnp.float32)
-    coeffs = jnp.asarray(coeffs, jnp.float32)
-    if coeffs.ndim != 4:
-        raise ValueError(f"coeffs must be [Q, Mp, 3, 3], got {coeffs.shape}")
-    if backend == "ref":
-        # keep the [Q, chunk, M, 3] broadcast temp the same size as the
-        # single-query path's [chunk, M, 3] by shrinking chunk with Q
-        chunk = max(1024, _USER_CHUNK // max(int(coeffs.shape[0]), 1))
-        if xs.shape[0] > chunk:
-            return _raycast_batch_ref_chunked(xs, ys, coeffs, chunk=chunk)
-        return _raycast_batch_ref_jit(xs, ys, coeffs)
-    if backend != "pallas":
-        raise ValueError(f"unknown backend {backend!r}")
-    if interpret is None:
-        interpret = pallas_interpret_default()
-    n = xs.shape[0]
-    bu_eff, bm_eff = _effective_blocks(n, coeffs.shape[1], bu, bm)
-    xs_p = _pad1(xs, bu_eff, 0.0)
-    ys_p = _pad1(ys, bu_eff, 0.0)
-    A, B, C = _coeff_planes(coeffs, bm_eff)
-    out = raycast_count_batch_kernel_call(
-        xs_p, ys_p, A, B, C, bu=bu_eff, bm=bm_eff, interpret=bool(interpret)
-    )
-    return out[:, :n]
+    with span("verify.h2d", bytes=4 * int(np.prod(np.shape(coeffs)))):
+        xs = jnp.asarray(xs, jnp.float32)
+        ys = jnp.asarray(ys, jnp.float32)
+        coeffs = jnp.asarray(coeffs, jnp.float32)
+        if coeffs.ndim != 4:
+            raise ValueError(f"coeffs must be [Q, Mp, 3, 3], got {coeffs.shape}")
+        if backend == "ref":
+            # keep the [Q, chunk, M, 3] broadcast temp the same size as the
+            # single-query path's [chunk, M, 3] by shrinking chunk with Q
+            chunk = max(1024, _USER_CHUNK // max(int(coeffs.shape[0]), 1))
+            if xs.shape[0] > chunk:
+                return _raycast_batch_ref_chunked(xs, ys, coeffs, chunk=chunk)
+            return _raycast_batch_ref_jit(xs, ys, coeffs)
+        if backend != "pallas":
+            raise ValueError(f"unknown backend {backend!r}")
+        if interpret is None:
+            interpret = pallas_interpret_default()
+        n = xs.shape[0]
+        bu_eff, bm_eff = _effective_blocks(n, coeffs.shape[1], bu, bm)
+        xs_p = _pad1(xs, bu_eff, 0.0)
+        ys_p = _pad1(ys, bu_eff, 0.0)
+        A, B, C = _coeff_planes(coeffs, bm_eff)
+        out = raycast_count_batch_kernel_call(
+            xs_p, ys_p, A, B, C, bu=bu_eff, bm=bm_eff, interpret=bool(interpret)
+        )
+        return out[:, :n]
 
 
 #: Element budget for one [Q, chunk, block, L] edge-evaluation temp of the

@@ -9,7 +9,9 @@ Two layers with different cost contracts:
   :func:`span` still *times* its block (the engine consumes the elapsed
   time), but recording into the per-thread ring costs one branch until
   :func:`enable_tracing` flips it on.  Export the recording with
-  :func:`write_chrome_trace` and open it in ``chrome://tracing``.
+  :func:`write_chrome_trace` and open it in ``chrome://tracing``; while
+  a ``jax.profiler`` trace is also being taken, the spans land in it as
+  ``repro/<name>`` host events beside the device's operations.
 
 Quickstart::
 
@@ -25,6 +27,8 @@ from .trace import (
     Span,
     SpanRing,
     Tracer,
+    batch_scope,
+    current_batch,
     disable_tracing,
     enable_tracing,
     get_tracer,
@@ -61,6 +65,8 @@ __all__ = [
     "Span",
     "SpanRing",
     "Tracer",
+    "batch_scope",
+    "current_batch",
     "disable_tracing",
     "enable_tracing",
     "get_tracer",

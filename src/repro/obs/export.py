@@ -3,9 +3,9 @@
 ``chrome://tracing`` / Perfetto load the output of
 :func:`chrome_trace` directly: each span becomes a complete event
 (``ph: "X"``) with microsecond ``ts``/``dur``, the ring's thread id as
-``tid``, and the span attrs as ``args`` — so a sharded ``query_batch``
-renders as a ``batch`` bar with nested ``filter``/``verify`` bars and
-per-shard children under them.
+``tid``, and the span attrs and the engine's batch number as ``args`` —
+so a sharded ``query_batch`` renders as a ``batch`` bar with nested
+``filter``/``verify`` bars and per-shard children under them.
 
 :func:`summarize` is the text twin for terminals/CI logs, and
 :func:`metrics_snapshot` just re-exports the registry's flat dict so
@@ -61,7 +61,12 @@ def chrome_trace(tracer: Tracer | None = None) -> dict:
                 "tid": r["tid"],
                 "ts": (r["t0"] - t_base) * 1e6,
                 "dur": (r["t1"] - r["t0"]) * 1e6,
-                "args": {**r["attrs"], "seq": r["seq"], "parent": r["parent"]},
+                "args": {
+                    **r["attrs"],
+                    "seq": r["seq"],
+                    "parent": r["parent"],
+                    **({"batch": r["batch"]} if r["batch"] >= 0 else {}),
+                },
             }
         )
     return {
@@ -115,12 +120,14 @@ def _from_chrome(obj: dict) -> list[dict]:
         args = dict(ev.get("args", {}))
         seq = args.pop("seq", -1)
         parent = args.pop("parent", -1)
+        batch = args.pop("batch", -1)
         t0 = ev["ts"] / 1e6
         recs.append(
             {
                 "tid": ev.get("tid", 0),
                 "seq": seq,
                 "parent": parent,
+                "batch": batch,
                 "name": ev["name"],
                 "attrs": args,
                 "t0": t0,

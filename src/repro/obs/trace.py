@@ -11,11 +11,13 @@ fixed-size record to a per-thread ring buffer that
 
 Design constraints, in order:
 
-* **Hot-path overhead is one branch when disabled.**  A span always
+* **Hot-path overhead is two branches when disabled.**  A span always
   takes its two ``perf_counter`` readings (the engine needs the elapsed
   time regardless — that cost predates this module); everything else
-  (string interning, ring write) sits behind a single
-  ``if tracer.enabled`` test at span exit.
+  (the profiler annotation, string interning, ring write) sits behind
+  one ``if tracer.enabled`` test at span enter and the matching
+  ``if self._ann is not None`` at exit.  Whether a span is recorded is
+  decided when it opens, so ring records and annotations pair up.
 * **Lock-free under the MVCC read path.**  Each thread owns exactly one
   :class:`SpanRing` (single writer); record columns are preallocated
   numpy arrays, so a write is a handful of scalar stores with no
@@ -32,10 +34,20 @@ Span *attribution* (which backend, which shard, which snapshot version)
 travels as keyword attrs, interned process-wide into small integer ids
 so the record stays fixed-size; nesting is recorded explicitly
 (per-thread parent seq + depth) rather than inferred from timestamps.
+The engine's batch number is a plain int column of its own (interning a
+value that never repeats would saturate the attr table): a span given
+``batch=`` carries it, and every span opened inside it inherits it.
+
+While tracing is enabled every span also opens a
+``jax.profiler.TraceAnnotation("repro/<name>")``, so a profile taken at
+the same time holds the program's spans on the profiler's clock, beside
+the device's operations.  ``jax`` is imported when the first span is
+traced; with tracing off a span never touches it.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Iterator
@@ -50,12 +62,18 @@ __all__ = [
     "enable_tracing",
     "disable_tracing",
     "span",
+    "batch_scope",
+    "current_batch",
 ]
 
-#: Default per-thread ring capacity (records).  At ~6 spans per served
-#: batch this holds tens of thousands of batches; a long recording wraps
-#: and keeps the newest window, which is what a trace viewer wants.
+#: Default per-thread ring capacity (records).  At ~6 spans per cached
+#: batch, ~50 per batch of a dozen scene builds, this holds thousands of
+#: batches; a long recording wraps and keeps the newest window, which is
+#: what a trace viewer wants.
 DEFAULT_CAPACITY = 1 << 16
+
+#: Prefix of the profiler annotation each span opens while tracing is on.
+ANNOTATION_PREFIX = "repro/"
 
 #: Intern-table safety cap: attr combinations beyond this map to id 0
 #: ("overflow") instead of growing the table without bound (e.g. a
@@ -114,7 +132,7 @@ class SpanRing:
 
     __slots__ = (
         "tid", "capacity", "total",
-        "name_id", "attr_id", "t0", "t1", "depth", "parent",
+        "name_id", "attr_id", "t0", "t1", "depth", "parent", "batch",
     )
 
     def __init__(self, tid: int, capacity: int):
@@ -127,6 +145,7 @@ class SpanRing:
         self.t1 = np.zeros(capacity, np.float64)
         self.depth = np.zeros(capacity, np.int16)
         self.parent = np.full(capacity, -1, np.int64)
+        self.batch = np.full(capacity, -1, np.int64)
 
     @property
     def dropped(self) -> int:
@@ -134,7 +153,7 @@ class SpanRing:
         return max(self.total - self.capacity, 0)
 
     def write(self, name_id: int, attr_id: int, t0: float, t1: float,
-              depth: int, parent: int) -> int:
+              depth: int, parent: int, batch: int = -1) -> int:
         """Append one record; returns its seq.  Never blocks: a full
         ring wraps, dropping the oldest record (counted via ``total``)."""
         seq = self.total
@@ -145,6 +164,7 @@ class SpanRing:
         self.t1[i] = t1
         self.depth[i] = depth
         self.parent[i] = parent
+        self.batch[i] = batch
         self.total = seq + 1  # publish last (seqlock point)
         return seq
 
@@ -160,6 +180,7 @@ class SpanRing:
             t1=self.t1.copy(),
             depth=self.depth.copy(),
             parent=self.parent.copy(),
+            batch=self.batch.copy(),
         )
         after = self.total
         lo = max(after - self.capacity, 0)
@@ -168,28 +189,50 @@ class SpanRing:
 
 class Span:
     """One timed phase.  Always measures (``elapsed_s`` is the replaced
-    ``perf_counter`` pair); records into the thread's ring only when the
-    owning tracer is enabled at exit."""
+    ``perf_counter`` pair); records into the thread's ring, and opens a
+    profiler annotation, only when the owning tracer is enabled as the
+    span opens.
 
-    __slots__ = ("tracer", "name", "attrs", "t0", "t1", "seq", "_parent", "_depth")
+    ``batch`` is the engine's batch number; ``None`` inherits it from the
+    enclosing span (or the thread's :func:`batch_scope`), ``-1`` is none.
+    It may be set while the span is open: the ring takes it at exit.
+    """
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: dict | None):
+    __slots__ = (
+        "tracer", "name", "attrs", "t0", "t1", "seq", "batch",
+        "_parent", "_depth", "_ann",
+    )
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict | None,
+                 batch: int | None = None):
         self.tracer = tracer
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0
         self.t1 = 0.0
         self.seq = -1
+        self.batch = batch
+        self._ann = None
 
     @property
     def elapsed_s(self) -> float:
         return (self.t1 if self.t1 else time.perf_counter()) - self.t0
 
     def __enter__(self) -> "Span":
-        stack = self.tracer._stack()
+        tracer = self.tracer
+        stack = tracer._stack()
         self._depth = len(stack)
-        self._parent = stack[-1].seq if stack else -1
+        if stack:
+            self._parent = stack[-1].seq
+            if self.batch is None:
+                self.batch = stack[-1].batch
+        else:
+            self._parent = -1
+            if self.batch is None:
+                self.batch = getattr(tracer._local, "batch", -1)
         stack.append(self)
+        if tracer.enabled:  # the one hot-path branch at enter
+            self._ann = tracer._annotate(self)
         self.t0 = time.perf_counter()
         return self
 
@@ -203,8 +246,9 @@ class Span:
             stack.pop()
         elif self in stack:  # tolerate exception-skewed exits
             stack.remove(self)
-        if tracer.enabled:  # the one hot-path branch
+        if self._ann is not None:  # opened while tracing was enabled
             self.seq = tracer._record(self)
+            self._ann.__exit__(None, None, None)
 
 
 class Tracer:
@@ -226,6 +270,7 @@ class Tracer:
         self._local = threading.local()
         self._rings: dict[int, SpanRing] = {}
         self._rings_lock = threading.Lock()
+        self._annotation = None  # jax.profiler.TraceAnnotation, once traced
 
     # ---- per-thread state -------------------------------------------------
     def _stack(self) -> list:
@@ -252,8 +297,20 @@ class Tracer:
             else 0
         )
         return self._ring().write(
-            name_id, attr_id, sp.t0, sp.t1, sp._depth, sp._parent
+            name_id, attr_id, sp.t0, sp.t1, sp._depth, sp._parent, sp.batch
         )
+
+    def _annotate(self, sp: Span):
+        """Open ``sp``'s profiler annotation, with its batch number."""
+        cls = self._annotation
+        if cls is None:  # the first span traced: import jax only now
+            from jax.profiler import TraceAnnotation
+
+            cls = self._annotation = TraceAnnotation
+        name = ANNOTATION_PREFIX + sp.name
+        ann = cls(name, batch=sp.batch) if sp.batch >= 0 else cls(name)
+        ann.__enter__()
+        return ann
 
     # ---- control ----------------------------------------------------------
     def enable(self) -> "Tracer":
@@ -283,8 +340,8 @@ class Tracer:
             rings = list(self._rings.values())
         return sum(r.dropped for r in rings)
 
-    def span(self, name: str, **attrs) -> Span:
-        return Span(self, name, attrs or None)
+    def span(self, name: str, *, batch: int | None = None, **attrs) -> Span:
+        return Span(self, name, attrs or None, batch)
 
     def records(self) -> Iterator[dict]:
         """Decoded stable records across all rings (oldest-first per
@@ -305,6 +362,7 @@ class Tracer:
                     t1=float(cols["t1"][i]),
                     depth=int(cols["depth"][i]),
                     parent=int(cols["parent"][i]),
+                    batch=int(cols["batch"][i]),
                 )
 
 
@@ -337,11 +395,32 @@ def disable_tracing() -> Tracer:
     return get_tracer().disable()
 
 
-def span(name: str, **attrs) -> Span:
+def span(name: str, *, batch: int | None = None, **attrs) -> Span:
     """A nestable timed span on the global tracer.
 
     Always measures (use ``sp.elapsed_s`` after the block — this *is*
     the engine's perf-counter pair); records into the per-thread ring
-    only while tracing is enabled.
+    only while tracing is enabled.  ``batch`` numbers the engine batch
+    the span belongs to; nested spans inherit it.
     """
-    return Span(_TRACER, name, attrs or None)
+    return Span(_TRACER, name, attrs or None, batch)
+
+
+def current_batch() -> int:
+    """The batch number of the innermost span open on this thread, or
+    the thread's :func:`batch_scope` (``-1``: none)."""
+    stack = _TRACER._stack()
+    return stack[-1].batch if stack else getattr(_TRACER._local, "batch", -1)
+
+
+@contextlib.contextmanager
+def batch_scope(batch: int):
+    """Spans this thread opens outside any open span carry ``batch``: a
+    pool worker that builds part of a batch joins that batch's number."""
+    local = _TRACER._local
+    prev = getattr(local, "batch", -1)
+    local.batch = batch
+    try:
+        yield
+    finally:
+        local.batch = prev
