@@ -27,6 +27,22 @@ The cheap InfZone filters are kept verbatim:
 Three strategies from paper §4.8 are exposed: ``"infzone"``,
 ``"conservative"`` (full test for the first ``warmup`` facilities, Eq. (1)
 only afterwards) and ``"none"``.
+
+**Live-cell compaction.**  Only possibly-zone (*live*) cells can change a
+verdict, so only they are tested.  Each chunk's bisectors are evaluated
+once, at the corners of live cells (``x·n0 + y·n1 - c`` in float64, the
+same arithmetic per corner as a full-grid evaluation, so a corner lying
+on a bisector resolves the same way).  Every live corner belongs to a
+live cell, so "every live cell fully valid" is "every live corner
+valid"; the survivors' coverage update reuses the same signs.  Corner
+distances to ``q`` are computed once per query.  A cell that reaches
+``k`` is dropped from the live set and its count is never updated again:
+counts only grow, so a dead cell stays at or above ``k`` whatever its
+stale count, and nothing reads it (not the cover test, not the zone
+radius, not the early exit).  The result — keep mask and every
+``PruneStats`` field — equals the full-grid loop's exactly;
+``prune.cells{kind=tested|grid}`` counts the cells tested against G² per
+full-test chunk.
 """
 
 from __future__ import annotations
@@ -38,10 +54,19 @@ import numpy as np
 
 from repro.core.geometry import Rect, bisector
 from repro.core.grid import build_sleep, build_yield_ratio
+from repro.obs.metrics import process_registry
 
 __all__ = ["PruneStats", "prune_facilities", "STRATEGIES", "adaptive_grid"]
 
 STRATEGIES = ("infzone", "conservative", "none")
+
+#: Per full-test chunk: the live cells it evaluated, and the whole grid
+#: (G²).  Their ratio is how far the live-cell compaction engages.
+_TESTED = process_registry().counter("prune.cells", kind="tested")
+_GRID = process_registry().counter("prune.cells", kind="grid")
+
+#: Bit ``s % 8`` for occluder row ``s``: packs 8 rows of flags into a byte.
+_BIT_WEIGHTS = np.tile(np.left_shift(1, np.arange(8)).astype(np.uint8), 8)
 
 #: Adaptive coverage-grid resolution: facility sets below the threshold
 #: prune at the coarse resolution, denser ones at the fine one (measured:
@@ -89,65 +114,83 @@ class PruneStats:
 
 
 class _CoverageGrid:
-    """Full-containment coverage counts over a G x G cell grid."""
+    """Full-containment coverage counts of the *live* cells of a G x G grid.
 
-    def __init__(self, rect: Rect, grid: int):
-        self.rect = rect
+    A cell is live while its count is below ``k`` (possibly-zone).  Only
+    live cells are kept, as the flat lattice index of their low corner
+    (``cells``) beside their counts; the corners any live cell touches
+    are gathered once per live-set change (``px``/``py``, and ``quad``:
+    each live cell's four corners as slots of that list).  A cell that
+    reaches ``k`` is dropped for good: counts only grow.
+    """
+
+    def __init__(self, rect: Rect, grid: int, q: np.ndarray, k: int):
         self.G = grid
-        xs = np.linspace(rect.xmin, rect.xmax, grid + 1)
-        ys = np.linspace(rect.ymin, rect.ymax, grid + 1)
-        cx, cy = np.meshgrid(xs, ys, indexing="ij")  # corner lattice [G+1, G+1]
-        self._corners = np.stack([cx, cy], axis=-1)
-        self.counts = np.zeros((grid, grid), dtype=np.int32)
+        W = grid + 1
+        self._xs = np.linspace(rect.xmin, rect.xmax, W)
+        self._ys = np.linspace(rect.ymin, rect.ymax, W)
+        # squared corner-to-q distances, fixed for the whole query
+        self._d2 = (
+            np.square(self._xs - q[0])[:, None] + np.square(self._ys - q[1])[None]
+        ).ravel()
+        first = np.arange(grid if k > 0 else 0)
+        self.cells = (first[:, None] * W + first[None]).ravel()
+        self.counts = np.zeros(len(self.cells), dtype=np.int32)
+        self._slot = np.empty(W * W, dtype=np.intp)
+        self._index()
 
-    def _corner_signed(self, n: np.ndarray, c: float) -> np.ndarray:
-        return self._corners @ np.asarray(n, dtype=np.float64) - c
+    def _index(self) -> None:
+        """Gather the live cells' corners and each cell's four slots."""
+        W = self.G + 1
+        f = self.cells
+        quad_flat = (f, f + W, f + 1, f + W + 1)
+        used = np.zeros(W * W, dtype=bool)
+        for idx in quad_flat:
+            used[idx] = True
+        self._corner_idx = np.flatnonzero(used)
+        self.px = self._xs[self._corner_idx // W]
+        self.py = self._ys[self._corner_idx % W]
+        self._slot[self._corner_idx] = np.arange(len(self._corner_idx))
+        self.quad = tuple(self._slot[idx] for idx in quad_flat)
 
-    def corner_signed_batch(self, n: np.ndarray, c: np.ndarray) -> np.ndarray:
-        """[B, G+1, G+1] signed values for a batch of half-planes."""
-        v = np.einsum("xyk,bk->bxy", self._corners, np.asarray(n, dtype=np.float64))
-        return v - np.asarray(c, dtype=np.float64)[:, None, None]
+    def signed(self, n: np.ndarray, c: np.ndarray) -> np.ndarray:
+        """``[B, C]`` values ``x·n0 + y·n1 - c`` at the live corners."""
+        v = np.multiply.outer(n[:, 0], self.px)
+        v += np.multiply.outer(n[:, 1], self.py)
+        v -= c[:, None]
+        return v
 
-    def _cell_all(self, corner_mask: np.ndarray) -> np.ndarray:
-        """AND of the 4 corner flags per cell: ``[G, G]``."""
-        return (
-            corner_mask[:-1, :-1]
-            & corner_mask[1:, :-1]
-            & corner_mask[:-1, 1:]
-            & corner_mask[1:, 1:]
-        )
+    def add_invalid(self, valid: np.ndarray, k: int) -> None:
+        """Count each live cell whose 4 corners are strictly invalid for a
+        kept occluder (``valid``: ``[S, C]`` corner flags ``p.n >= c``),
+        then drop the cells that reached ``k``."""
+        # 8 occluders a byte: AND the 4 corners' packed invalid bits per
+        # cell, then count the set bits (pad rows are 0 and stay 0)
+        S, C = valid.shape
+        nb = -(-S // 8)
+        bits = np.zeros((nb * 8, C), dtype=np.uint8)
+        bits[:S] = ~valid
+        bits *= _BIT_WEIGHTS[: nb * 8, None]
+        inv = bits.reshape(nb, 8, C).sum(axis=1, dtype=np.uint8)  # [nb, C]
+        a, b, c, d = (np.take(inv, idx, axis=1) for idx in self.quad)
+        self.counts += np.bitwise_count(a & b & c & d).sum(axis=0, dtype=np.uint8)
+        live = self.counts < k
+        if not live.all():
+            self.cells = self.cells[live]
+            self.counts = self.counts[live]
+            self._index()
 
-    def add_halfplane(self, n: np.ndarray, c: float) -> None:
-        """Register a kept occluder's invalid half-plane ``p.n < c``."""
-        strictly_invalid = self._corner_signed(n, c) < 0.0
-        self.counts += self._cell_all(strictly_invalid).astype(np.int32)
-
-    def possibly_zone(self, k: int) -> np.ndarray:
-        """Cells that may still contain influence-zone points: ``[G, G]``."""
-        return self.counts < k
-
-    def fully_valid_for(self, n: np.ndarray, c: float) -> np.ndarray:
-        """Cells with no strictly-invalid point for this bisector."""
-        valid = self._corner_signed(n, c) >= 0.0
-        return self._cell_all(valid)
-
-    def zone_radius(self, k: int, q: np.ndarray) -> float:
-        """max over possibly-zone cell corners of dist(corner, q).
+    def zone_radius(self) -> float:
+        """max over live cells' corners of dist(corner, q).
 
         dist(., q) is convex so the per-cell max is attained at a corner;
         taking all corners of possibly-zone cells upper-bounds the zone's
         max distance (Eq. (1) soundness).
         """
-        pz = self.possibly_zone(k)
-        if not pz.any():
+        if not len(self.cells):
             return 0.0
-        mask = np.zeros((self.G + 1, self.G + 1), dtype=bool)
-        mask[:-1, :-1] |= pz
-        mask[1:, :-1] |= pz
-        mask[:-1, 1:] |= pz
-        mask[1:, 1:] |= pz
-        d = np.linalg.norm(self._corners - np.asarray(q, dtype=np.float64), axis=-1)
-        return float(d[mask].max())
+        # sqrt is monotone: the max distance is the sqrt of the max square
+        return float(np.sqrt(self._d2[self._corner_idx].max()))
 
 
 def prune_facilities(
@@ -189,11 +232,14 @@ def prune_facilities(
         return keep, PruneStats(M, int(keep.sum()), 0, 0, strategy)
 
     dist_q = np.linalg.norm(facilities - q, axis=1)
-    order = order_all = np.argsort(dist_q, kind="stable")
+    order = np.argsort(dist_q, kind="stable")
     order = order[alive[order]]
-    cov = _CoverageGrid(rect, grid)
+    cov = _CoverageGrid(rect, grid, q, k)
+    n_kept = 0
     n_eq1 = 0
     n_cover = 0
+    n_tested = 0  # live cells the full-test chunks evaluated
+    n_full = 0  # full-test chunks
     radius = np.inf  # zone radius upper bound; tightened as occluders land
     processed = 0
     max_processed = 0.0  # farthest facility any chunk examined
@@ -214,7 +260,7 @@ def prune_facilities(
     while pos < len(order):
         yield_ratio = build_yield_ratio()  # per iteration: may be dynamic
         t_iter = time.perf_counter() if yield_ratio else 0.0
-        chunk = 8 if keep.sum() < 4 * k + 8 else 64
+        chunk = 8 if n_kept < 4 * k + 8 else 64
         # ---- Eq. (1) bulk reject of everything beyond 2*radius ----------
         if radius < np.inf:
             cut = np.searchsorted(dist_q[order], 2.0 * radius, side="right")
@@ -229,35 +275,35 @@ def prune_facilities(
         processed_batch = processed
         processed += len(batch)
         max_processed = max(max_processed, float(dist_q[batch[-1]]))
-        n_b, c_b = bisector(facilities[batch], q)  # [B, 2], [B]
         full_test = strategy == "infzone" or processed_batch < warmup
+        if full_test and not len(cov.cells):
+            n_cover += len(batch) + (len(order) - pos)
+            break
+        n_b, c_b = bisector(facilities[batch], q)  # [B, 2], [B]
+        valid = cov.signed(n_b, c_b) >= 0.0  # [B, live corners]
         if full_test:
-            pz = cov.possibly_zone(k)
-            if not pz.any():
-                n_cover += len(batch) + (len(order) - pos)
-                break
-            # vectorized: cell fully-valid per batch facility  [B, G, G]
-            sgn = cov.corner_signed_batch(n_b, c_b) >= 0.0  # [B, G+1, G+1]
-            fv = sgn[:, :-1, :-1] & sgn[:, 1:, :-1] & sgn[:, :-1, 1:] & sgn[:, 1:, 1:]
-            covered = (~pz[None] | fv).all(axis=(1, 2))  # [B]
-            survivors = batch[~covered]
+            # every live cell fully valid <=> every live corner valid
+            covered = valid.all(axis=1)  # [B]
             n_cover += int(covered.sum())
+            n_tested += len(cov.cells)
+            n_full += 1
+            survivors = batch[~covered]
+            valid = valid[~covered]
         else:
             survivors = batch
         if len(survivors):
             keep[survivors] = True
-            ns, cs = bisector(facilities[survivors], q)
-            inv = cov.corner_signed_batch(ns, cs) < 0.0
-            full_inv = (
-                inv[:, :-1, :-1] & inv[:, 1:, :-1] & inv[:, :-1, 1:] & inv[:, 1:, 1:]
-            )
-            cov.counts += full_inv.sum(axis=0).astype(np.int32)
-            radius = cov.zone_radius(k, q)
+            n_kept += len(survivors)
+            cov.add_invalid(valid, k)
+            radius = cov.zone_radius()
         if yield_ratio:
             build_sleep((time.perf_counter() - t_iter) * yield_ratio)
 
+    if n_full:
+        _TESTED.inc(n_tested)
+        _GRID.inc(n_full * grid * grid)
     safe_radius = (
         max(2.0 * float(radius), max_processed) if np.isfinite(radius) else np.inf
     )
-    stats = PruneStats(M, int(keep.sum()), n_eq1, n_cover, strategy, safe_radius)
+    stats = PruneStats(M, n_kept, n_eq1, n_cover, strategy, safe_radius)
     return keep, stats

@@ -623,7 +623,9 @@ class DynamicEngine(RkNNEngine):
                 if store:
                     new_store = {}
                     refitted: dict[int, tuple] = {}  # grid/grid-pallas share one build
-                    for (bname, g), index in store.items():
+                    # readers of the old version may still add indexes to
+                    # this store: walk a copy (list() is one C-level pass)
+                    for (bname, g), index in list(store.items()):
                         if index is None:  # index-less backend (dense paths)
                             new_store[(bname, g)] = None
                             continue
