@@ -16,7 +16,9 @@ import glob
 import gzip
 import os
 
-__all__ = ["Event", "Trace", "WINDOW_MARK", "OUTSIDE", "merge", "find_xplane", "label_gaps"]
+__all__ = [
+    "Event", "Trace", "WINDOW_MARK", "OUTSIDE", "merge", "overlap_ns", "find_xplane", "label_gaps",
+]
 
 WINDOW_MARK = "chipbench_window"
 #: Label of an idle gap in which no span of the system was open: the host
@@ -156,18 +158,34 @@ class Trace:
                 n += 1
         return total, n
 
+    def busy_union(self, lo: float, hi: float) -> list[tuple[float, float]]:
+        """Merged intervals in which any device ran an operation."""
+        return merge(iv for d in self.devices for iv in self.busy(d, lo, hi))
+
     def idle_gaps(self, lo: float, hi: float) -> list[tuple[float, float]]:
-        """Gaps of the first device with operations, inside the window."""
-        for d in sorted(self.devices):
-            busy = self.busy(d, lo, hi)
-            if busy:
-                edges = [lo] + [t for iv in busy for t in iv] + [hi]
-                return [
-                    (edges[i], edges[i + 1])
-                    for i in range(0, len(edges), 2)
-                    if edges[i + 1] > edges[i]
-                ]
-        return [(lo, hi)]
+        """Gaps inside the window in which no device ran an operation: the
+        gaps of the union of every device's busy intervals."""
+        edges = [lo] + [t for iv in self.busy_union(lo, hi) for t in iv] + [hi]
+        return [
+            (edges[i], edges[i + 1])
+            for i in range(0, len(edges), 2)
+            if edges[i + 1] > edges[i]
+        ]
+
+
+def overlap_ns(a: list[tuple[float, float]], b: list[tuple[float, float]]) -> float:
+    """Total length of the intersection of two sorted, disjoint interval
+    lists."""
+    total = 0.0
+    i = 0
+    for s, e in a:
+        while i < len(b) and b[i][1] <= s:
+            i += 1
+        j = i
+        while j < len(b) and b[j][0] < e:
+            total += min(e, b[j][1]) - max(s, b[j][0])
+            j += 1
+    return total
 
 
 def label_gaps(

@@ -1,10 +1,11 @@
-"""Share of the device's idle time in the window during which no span of
+"""Share of the devices' idle time in the window during which no span of
 the system was open on any host thread: the ``repro/<span>`` annotations
 the system writes into the profiler's trace while tracing is on, against
-the device's idle intervals, both on the trace's own clock.  ``None`` where
+the intervals in which no device ran an operation, both on the trace's own
+clock.  ``None`` where
 the window holds no such annotation (a system that writes none)."""
 
-from chipbench.xplane import merge
+from chipbench.xplane import merge, overlap_ns
 
 PREFIX = "repro/"
 
@@ -22,13 +23,4 @@ def read(ctx):
     idle = sum(e - s for s, e in gaps)
     if idle <= 0.0:
         return 0.0
-    covered = 0.0
-    i = 0
-    for s, e in gaps:  # both lists sorted and disjoint
-        while i < len(marks) and marks[i][1] <= s:
-            i += 1
-        j = i
-        while j < len(marks) and marks[j][0] < e:
-            covered += min(e, marks[j][1]) - max(s, marks[j][0])
-            j += 1
-    return 100.0 * (idle - covered) / idle
+    return 100.0 * (idle - overlap_ns(gaps, marks)) / idle

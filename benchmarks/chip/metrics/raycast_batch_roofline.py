@@ -1,6 +1,10 @@
 """The batched ray-cast kernel's share of its roofline: the least time the
 chip needs for the window's batches (``chipbench.work``, counted from each
-query's real triangles) over the kernel's device time in the trace."""
+query's real triangles) over the kernel's device time in the trace.
+
+The least time is one chip's, for all the window's users; the kernel time
+is summed over the chips that ran it.  On n chips the share is therefore
+of their combined peak over the kernel's time on each."""
 
 from chipbench.kernels import RAYCAST_BATCH
 from chipbench.work import least_time, raycast_batch_work

@@ -97,12 +97,24 @@ def use_checkout_cache(jax) -> None:
 
 def make_engine(cfg: dict, facilities, users):
     """The configuration's engine, built with its ``engine_options`` as
-    they stand (the engine rejects an option it does not know)."""
-    from repro.core import RkNNEngine
+    they stand (the engine rejects an option it does not know).  A
+    ``ShardedEngine`` puts one shard on each chip the configuration asks
+    for, so its ``shards`` has to equal ``chips``."""
+    options = cfg["engine_options"]
+    if cfg["engine"] == "RkNNEngine":
+        from repro.core import RkNNEngine
 
-    if cfg["engine"] != "RkNNEngine":
-        raise RunError(f"unknown engine {cfg['engine']!r}")
-    return RkNNEngine(facilities, users, **cfg["engine_options"])
+        return RkNNEngine(facilities, users, **options)
+    if cfg["engine"] == "ShardedEngine":
+        if options.get("shards") != cfg["chips"]:
+            raise RunError(
+                f"ShardedEngine shards={options.get('shards')!r} differs from "
+                f"chips={cfg['chips']!r}: one shard a chip"
+            )
+        from repro.shard import ShardedEngine
+
+        return ShardedEngine(facilities, users, **options)
+    raise RunError(f"unknown engine {cfg['engine']!r}")
 
 
 def compile_counters() -> dict:

@@ -28,6 +28,7 @@ SMALL = {
     "cal_f1000_k10.uniform": dict(points=8000, facilities=200, check_batches=2),
     "cal_f1000_k10.siting": dict(points=8000, check_batches=2),
     "usa_f1000_k100.uniform": dict(points=8000, check_batches=2),
+    "usa_f1000_k100_x4.uniform": dict(points=8000, check_batches=2),
 }
 SEED = 2**31 + 7
 
@@ -110,13 +111,46 @@ def _half_batch(real, xs, ys, coeffs, **kw):
 
 
 @pytest.mark.parametrize("fault", [_alter_first_answer, _half_batch], ids=["altered", "half"])
-@pytest.mark.parametrize("workload", ["cal_f1000_k10.uniform", "cal_f1000_k10.siting"])
+@pytest.mark.parametrize(
+    "workload",
+    ["cal_f1000_k10.uniform", "cal_f1000_k10.siting", "usa_f1000_k100_x4.uniform"],
+)
 def test_broken_timed_path_is_not_correct(monkeypatch, workload, fault):
     _faulty(monkeypatch, fault)
     result = _run(workload)
     assert result["correct"] is False
     assert result["failed"] > 0
     assert result["checks"]["wrong_members"]["value"] > 0
+
+
+def test_lost_shard_is_not_correct(monkeypatch):
+    """The exchange between chips left out: the last shard's counts never
+    reach the reassembled answer (its users read 0)."""
+    from repro.shard import engine as shard_engine
+
+    real = shard_engine.ShardDispatch.__call__
+
+    def lose_last_shard(self, prepared):
+        out = np.array(real(self, prepared))
+        last = self.state.views[-1]
+        out[:, self.state.perm[last.lo:last.hi]] = 0
+        return out
+
+    monkeypatch.setattr(shard_engine.ShardDispatch, "__call__", lose_last_shard)
+    result = _run("usa_f1000_k100_x4.uniform")
+    assert result["correct"] is False
+    assert result["checks"]["wrong_members"]["value"] > 0
+
+
+def test_sharded_engine_needs_one_shard_a_chip():
+    net = RoadNetwork(2000, 3)
+    facilities, users = facility_user_split(net.points, 100, 3)
+    cfg = {"engine": "ShardedEngine", "engine_options": {"backend": "dense", "shards": 2},
+           "chips": 4}
+    with pytest.raises(run.RunError, match="shards=2 differs from chips=4"):
+        run.make_engine(cfg, facilities, users)
+    with pytest.raises(run.RunError, match="unknown engine"):
+        run.make_engine({**cfg, "engine": "OtherEngine"}, facilities, users)
 
 
 def test_no_tpu_exits_nonzero_and_prints_no_result():

@@ -27,7 +27,8 @@ SPAN_READERS = {
     "mask_ms_per_batch": "mask",
     "stream_wait_ms_per_batch": "stream.wait",
 }
-NEW = [*SPAN_READERS, "idle_unattributed_pct"]
+#: Readers that read a number from a CPU run (no device planes there).
+NEW = [*SPAN_READERS, "idle_unattributed_pct", "shard_host_ms_per_batch"]
 
 
 def _ctx(spans=(), host=(), ops=(), batches=4):
@@ -109,6 +110,7 @@ def test_kernel_trace_name_is_pinned():
 SMALL = {
     "cal_f1000_k10.uniform": dict(points=8000, facilities=200, check_batches=2),
     "cal_f1000_k10.siting": dict(points=8000, check_batches=2),
+    "usa_f1000_k100_x4.uniform": dict(points=8000, check_batches=2),
 }
 
 
@@ -129,3 +131,28 @@ def test_traced_run_reports_the_new_metrics(workload):
     assert 0.0 <= result["metrics"]["idle_unattributed_pct"]["value"] <= 100.0
     for name in ("filter_ms_per_batch", "verify_ms_per_batch"):
         assert result["metrics"][name]["value"] > 0.0
+
+
+def test_shard_host_is_shard_verify_time_with_every_chip_idle():
+    # two shards a batch, two batches; shard-verify spans [0,300) [300,600)
+    # and [600,800) [800,1000); kernels run [100,250) on chip 0 and
+    # [350,550) on chip 1; the second batch has no kernel in the window
+    trace = Trace(
+        [Event(WINDOW_MARK, 0.0, 1000.0),
+         Event("repro/verify", 0.0, 1000.0),
+         *(Event("repro/shard-verify", s, e)
+           for s, e in [(0.0, 300.0), (300.0, 600.0), (600.0, 800.0), (800.0, 1200.0)])],
+        {"/device:TPU:0": [Event("%k.1 = custom-call", 100.0, 250.0)],
+         "/device:TPU:1": [Event("%k.2 = custom-call", 350.0, 550.0)]},
+    )
+    ctx = types.SimpleNamespace(batches=2, trace=trace, trace_window=(0.0, 1000.0))
+    idle_ns = 1000.0 - 150.0 - 200.0
+    assert run.read_metric("shard_host_ms_per_batch", ctx) == pytest.approx(idle_ns / 1e6 / 2)
+
+
+def test_shard_host_finds_nothing_without_shards():
+    ops = [Event("%k.1 = custom-call", 100.0, 300.0)]
+    host = [Event("repro/verify", 0.0, 1000.0), Event("repro/shard-verify", 1000.0, 2000.0)]
+    assert run.read_metric("shard_host_ms_per_batch", _ctx(host=host, ops=ops)) is None
+    host = [Event("repro/shard-verify", 0.0, 500.0)]
+    assert run.read_metric("shard_host_ms_per_batch", _ctx(host=host, ops=ops, batches=0)) is None

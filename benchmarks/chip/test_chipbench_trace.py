@@ -1,7 +1,8 @@
 """CPU tests of the trace reduction on a small trace recorded on the chip:
 the CAL configuration at 200,000 points, batches of 16 facilities whose
 scenes were all cached, the profiler on for a short window
-(``testdata/cal_tiny.xplane.pb.gz``)."""
+(``testdata/cal_tiny.xplane.pb.gz``); and of the reduction over several
+chips on synthetic two-chip traces."""
 
 from __future__ import annotations
 
@@ -94,3 +95,50 @@ def test_gaps_are_labelled_by_the_deepest_open_span():
     assert label_gaps(gaps, spans) == [
         ("verify", 20e-9), ("batch", 10e-9), ("batch", 4e-9), (OUTSIDE, 10e-9),
     ]
+
+
+def _first_device_gaps(trace, lo, hi):
+    """The gaps as the reduction took them before it read several chips:
+    those of the first device with operations."""
+    for d in sorted(trace.devices):
+        busy = trace.busy(d, lo, hi)
+        if busy:
+            edges = [lo] + [t for iv in busy for t in iv] + [hi]
+            return [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
+                    if edges[i + 1] > edges[i]]
+    return [(lo, hi)]
+
+
+def test_one_chip_idle_gaps_are_the_first_devices(trace):
+    lo, hi = trace.window()
+    assert trace.idle_gaps(lo, hi) == _first_device_gaps(trace, lo, hi)
+    assert trace.busy_union(lo, hi) == trace.busy("/device:TPU:0", lo, hi)
+
+
+def _two_chips(ops0, ops1):
+    return Trace(
+        [Event("chipbench_window", 0.0, 1000.0)],
+        {"/device:TPU:0": [Event("%k.1 = custom-call", s, e) for s, e in ops0],
+         "/device:TPU:1": [Event("%k.2 = custom-call", s, e) for s, e in ops1]},
+    )
+
+
+def test_idle_gaps_are_where_no_chip_runs():
+    # chip 0 idles over [300, 600), which chip 1 covers from 250 to 650
+    tr = _two_chips([(100.0, 300.0), (600.0, 700.0)], [(250.0, 650.0)])
+    assert tr.idle_gaps(0.0, 1000.0) == [(0.0, 100.0), (700.0, 1000.0)]
+    assert tr.busy_union(0.0, 1000.0) == [(100.0, 700.0)]
+    assert _first_device_gaps(tr, 0.0, 1000.0) == [(0.0, 100.0), (300.0, 600.0), (700.0, 1000.0)]
+
+
+def _concurrency(trace):
+    ctx = types.SimpleNamespace(trace=trace, trace_window=(0.0, 1000.0))
+    return run.read_metric("chip_concurrency", ctx)
+
+
+def test_chip_concurrency_counts_chips_busy_together():
+    assert _concurrency(_two_chips([(100.0, 300.0)], [(300.0, 500.0)])) == pytest.approx(1.0)
+    assert _concurrency(_two_chips([(100.0, 300.0)], [(100.0, 300.0)])) == pytest.approx(2.0)
+    # half of chip 1's 200 ns overlaps chip 0: 400 ns of work in 300 ns
+    assert _concurrency(_two_chips([(100.0, 300.0)], [(200.0, 400.0)])) == pytest.approx(4 / 3)
+    assert _concurrency(Trace([Event("chipbench_window", 0.0, 1000.0)], {})) is None
